@@ -1,14 +1,14 @@
-"""Kernel micro-bench: CPU wall time of the public ops (ref backend —
-the Pallas path targets TPU and is validated in interpret mode by tests)
-plus the bandit-step itself (the paper's per-sample decision cost).
+"""Kernel micro-bench: wall time of the public ops (ref backend) plus
+the bandit-step itself (the paper's per-sample decision cost).
 
 Also benchmarks the FUSED exit epilogue (exit-norm + head matmul +
 online softmax as one program) against the unfused norm-then-confidence
-pair, and autotunes the fused kernel's ``block_b x block_v`` grid: on a
-TPU the sweep times the real Pallas kernel; on CPU it falls back to the
-interpreter on a reduced shape, which validates every block config but
-whose timings measure the interpreter, not the kernel (rows carry the
-backend so readers can tell).
+pair, and autotunes the fused kernel's ``block_b x block_v`` grid with
+the real Pallas kernel. That needs a TPU: without one the run fails.
+``--smoke`` instead runs tiny shapes anywhere, with the sweep under the
+Pallas interpreter on a reduced shape; it validates every block config,
+but its timings measure the interpreter, not the kernel. Every row names
+the device it ran on.
 
     PYTHONPATH=src:. python benchmarks/kernelbench.py [--smoke]
 """
@@ -43,7 +43,7 @@ def _time(fn, *args, iters=20, **kw):
     return (time.perf_counter() - t0) / iters * 1e6
 
 
-def run_fused_epilogue(key, rows, *, b, d, v, iters):
+def run_fused_epilogue(key, rows, *, b, d, v, iters, smoke):
     """Fused vs unfused exit epilogue, then the block autotune sweep."""
     x = jax.random.normal(key, (b, d))
     w = jax.random.normal(jax.random.fold_in(key, 1), (d, v)) * 0.02
@@ -62,10 +62,9 @@ def run_fused_epilogue(key, rows, *, b, d, v, iters):
     rows.append(f"kernel/exit_confidence_fused/ref,{us_f:.1f},"
                 f"unfused={us_un:.1f}us,speedup={us_un / us_f:.2f}x")
 
-    # ---- block autotune: real kernel on TPU, interpreter elsewhere ----
-    on_tpu = jax.default_backend() == "tpu"
-    backend = "pallas" if on_tpu else "pallas_interpret"
-    if not on_tpu:                     # interpreter is slow: shrink
+    # ---- block autotune: the real kernel; the interpreter under --smoke
+    backend = "pallas_interpret" if smoke else "pallas"
+    if smoke:                          # interpreter is slow: shrink
         b2, v2 = min(b, 8), min(v, 1024)
         x, w = x[:b2], w[:, :v2]
     tuned = []
@@ -85,6 +84,13 @@ def run_fused_epilogue(key, rows, *, b, d, v, iters):
 
 
 def run(print_csv: bool = True, smoke: bool = False, out_path: str = ""):
+    dev = jax.devices()[0]
+    if not smoke and dev.platform != "tpu":
+        raise SystemExit(f"kernelbench times the Pallas kernels and needs a "
+                         f"TPU; JAX found {dev.platform}. Use --smoke for "
+                         f"the interpreter at tiny shapes.")
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
     rows = []
     key = jax.random.PRNGKey(0)
     iters = 3 if smoke else 20
@@ -98,7 +104,8 @@ def run(print_csv: bool = True, smoke: bool = False, out_path: str = ""):
     rows.append(f"kernel/exit_confidence/ref,{us:.1f},"
                 f"bytes={gb:.3f}GB,eff_GBps={gb / (us / 1e6):.1f}")
 
-    tuned, best = run_fused_epilogue(key, rows, b=b, d=d, v=v, iters=iters)
+    tuned, best = run_fused_epilogue(key, rows, b=b, d=d, v=v, iters=iters,
+                                     smoke=smoke)
 
     # attention prefill (B=1, H=8, S, d=64), causal
     s = 128 if smoke else 1024
@@ -130,12 +137,17 @@ def run(print_csv: bool = True, smoke: bool = False, out_path: str = ""):
                conf_row, iters=20 if smoke else 200)
     rows.append(f"kernel/bandit_step,{us:.1f},per_sample_decision")
 
+    tag = f"device={dev.platform}/{dev.device_kind}"
+    rows = [f"{row},{tag}" for row in rows]
+    for r in tuned:
+        r["device"] = dev.device_kind
     if print_csv:
         for row in rows:
             print(row)
     if out_path:
         with open(out_path, "w") as f:
             json.dump({"benchmark": "kernelbench", "smoke": smoke,
+                       "device": device,
                        "rows": rows, "fused_autotune": tuned,
                        "fused_autotune_best": best}, f, indent=2)
         print(f"wrote {out_path}")

@@ -13,6 +13,9 @@ extrapolation stored under "extrapolated".) Also reports MODEL_FLOPS =
 6*N*D (train) / 2*N_active*D (inference) and the useful-compute ratio.
 
     PYTHONPATH=src python -m benchmarks.roofline [--dir DIR] [--compare tag]
+
+The dry run compiles for the production mesh of TPU v5e chips
+(launch/mesh.py), so the terms are bounded by that chip's peaks.
 """
 from __future__ import annotations
 
@@ -24,11 +27,24 @@ from typing import Dict, List
 
 from repro.configs import INPUT_SHAPES, get_config
 
-PEAK_FLOPS = 197e12          # bf16 / chip (TPU v5e)
-HBM_BW = 819e9               # B/s / chip
-ICI_BW = 50e9                # B/s / link
+# Per-chip peaks, keyed by jax's `Device.device_kind`. Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, and
+# 1,600 Gbit/s of inter-chip interconnect over 4 links (50 GB/s each).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
 
 DEFAULT_DIR = "benchmarks/results/dryrun"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peaks of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peaks for device_kind {device_kind!r}; known: "
+                       f"{sorted(PEAKS)} (add it with its source)")
+    return PEAKS[device_kind]
 
 
 def model_flops_per_device(arch: str, shape_name: str, num_devices: int):
@@ -60,14 +76,15 @@ def load_results(dir_: str, tag: str = "") -> List[Dict]:
 def analyze(r: Dict) -> Dict:
     # multi-pod passes run --no-fit (prove-it-lowers only): their raw
     # numbers count scan bodies once -> lower bounds, flagged in output
+    peak = peaks(DRYRUN_DEVICE_KIND)
     fitted = "extrapolated" in r
     ex = r.get("extrapolated", r)
     flops = ex["flops"]
     byts = ex["bytes_accessed"]
     coll = ex["collectives"]["total_bytes"]
-    t_comp = flops / PEAK_FLOPS
-    t_mem = byts / HBM_BW
-    t_coll = coll / ICI_BW
+    t_comp = flops / peak["flops"]
+    t_mem = byts / peak["hbm_bw"]
+    t_coll = coll / peak["ici_bw"]
     dom = max((t_comp, "compute"), (t_mem, "memory"),
               (t_coll, "collective"))[1]
     mf = model_flops_per_device(r["arch"], r["shape"], r["num_devices"])

@@ -18,13 +18,16 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma-separated subset of: " + ",".join(SECTIONS))
+    ap.add_argument("--smoke", action="store_true",
+                    help="kernels section at tiny shapes under the Pallas "
+                         "interpreter (without it that section needs a TPU)")
     args, _ = ap.parse_known_args()
     only = [s for s in args.only.split(",") if s] or SECTIONS
 
     print("name,us_per_call,derived")
     if "kernels" in only:
         from benchmarks import kernelbench
-        kernelbench.run()
+        kernelbench.run(smoke=args.smoke)
     if "table2" in only:
         from benchmarks import table2
         table2.run()

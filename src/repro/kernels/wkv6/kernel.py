@@ -30,7 +30,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sfin_ref, s_scr, *,
     def _init():
         s_scr[:] = jnp.zeros_like(s_scr)
 
-    u = u_ref[0].astype(jnp.float32)                     # (dk,)
+    u = u_ref[0, 0].astype(jnp.float32)                  # (dk,)
 
     def step(t, s):
         rt = r_ref[0, t].astype(jnp.float32)             # (dk,)
@@ -68,7 +68,9 @@ def wkv6_pallas(r, k, v, w, u, *, chunk: int = DEFAULT_CHUNK,
     def flat(x):
         return x.reshape(bh, t, x.shape[-1])
 
-    u_flat = jnp.broadcast_to(u[None], (b, h, dk)).reshape(bh, dk)
+    # (H, 1, dk): the block's last two dims are then the array's own, as
+    # TPU tiling requires; grid row g = batch * H + head reads head g % H
+    u3 = u[:, None, :]
 
     kern = functools.partial(_kernel, chunk=chunk, num_chunks=nc, seq_len=t)
     y, sfin = pl.pallas_call(
@@ -79,7 +81,7 @@ def wkv6_pallas(r, k, v, w, u, *, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, chunk, dk), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((1, chunk, dv), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((1, chunk, dk), lambda g, ci: (g, ci, 0)),
-            pl.BlockSpec((1, dk), lambda g, ci: (g, 0)),
+            pl.BlockSpec((1, 1, dk), lambda g, ci: (g % h, 0, 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, chunk, dv), lambda g, ci: (g, ci, 0)),
@@ -91,5 +93,5 @@ def wkv6_pallas(r, k, v, w, u, *, chunk: int = DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
         ),
         interpret=interpret,
-    )(flat(r), flat(k), flat(v), flat(w), u_flat)
+    )(flat(r), flat(k), flat(v), flat(w), u3)
     return y.reshape(b, h, t, dv), sfin.reshape(b, h, dk, dv)

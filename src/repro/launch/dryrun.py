@@ -1,5 +1,6 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production mesh with ShapeDtypeStruct stand-ins (no allocation).
@@ -14,8 +15,10 @@ the compiled HLO (all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute operand bytes), into a JSON the roofline benchmark
 reads.
 
-NOTE: the first two lines of this file set XLA_FLAGS before ANY other
+NOTE: the first lines of this file set XLA_FLAGS before ANY other
 import — jax locks the device count at first init. Do not move them.
+They do so only when the file runs as a script, so importing it (the
+unit tests do) leaves the importer's device count alone.
 (`from __future__` is consequently omitted — it must be line 1, which the
 XLA_FLAGS contract forbids.)
 """

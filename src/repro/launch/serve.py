@@ -34,12 +34,14 @@ import dataclasses
 import itertools
 import os
 import tempfile
+from typing import Optional
 
-from repro.configs import get_smoke_config
+from repro.configs import ModelConfig, get_config, get_smoke_config
 from repro.core import (CostModel, calibrate_alpha, confidence_cascade,
                         final_exit)
 from repro.data import OnlineStream, make_dataset
-from repro.data.synthetic import DOMAINS, VOCAB
+from repro.data.synthetic import DOMAINS, SEQ_LEN, VOCAB
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import exit_accuracy, train_classifier
 from repro.serving import (DecodeRuntime, EdgeCloudRuntime, ServingConfig,
                            serve)
@@ -50,25 +52,52 @@ from repro.serving.distributed import (ENV_COORDINATOR, ENV_KV_DIR,
                                        init_distributed_from_env)
 
 DEFAULT_SAMPLES = 1000
+PUBLISHED_SEQ_LEN = 128     # sequence length served at published widths
+# training rows per step at published widths: 64 rows x 128 tokens need
+# ~12 GB of activations at 12 x 768 without remat, too near a v5e's 16 GB
+PUBLISHED_TRAIN_BATCH = 32
 
 
-def build_testbed(*, layers: int = 6, steps: int = 300,
-                  calib_domain: str = "sst2_like",
+def testbed_config(*, published: bool = False, layers: Optional[int] = None,
+                   calib_domain: str = "sst2_like") -> ModelConfig:
+    """The float32 multi-exit classifier the serving drivers train.
+
+    By default the CPU-sized testbed: elasticbert12's family at 6 layers,
+    d_model 128, 4 heads, d_ff 512 and the synthetic data's 512-token
+    vocab. ``published=True`` keeps elasticbert12's published widths
+    (12 x 768, 12 heads, d_ff 3072, vocab 30522, one exit head per
+    layer); the synthetic token ids then occupy its first 512 vocab rows.
+    ``layers`` overrides the depth of either."""
+    if published:
+        cfg = get_config("elasticbert12")
+    else:
+        cfg = dataclasses.replace(
+            get_smoke_config("elasticbert12"), num_layers=6, d_model=128,
+            num_heads=4, num_kv_heads=4, d_ff=512, vocab_size=VOCAB)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    return dataclasses.replace(
+        cfg, num_classes=DOMAINS[calib_domain].num_classes, dtype="float32")
+
+
+def build_testbed(*, cfg: Optional[ModelConfig] = None, layers: int = 6,
+                  steps: int = 300, calib_domain: str = "sst2_like",
                   eval_domain: str = "imdb_like", n_train: int = 6144,
-                  n_eval: int = 4096, seed: int = 0):
+                  n_eval: int = 4096, seq_len: int = SEQ_LEN,
+                  batch_size: int = 64, seed: int = 0):
     """Train the multi-exit testbed (paper stage ii) and return everything
-    the serving phase needs."""
-    base = get_smoke_config("elasticbert12")
-    cfg = dataclasses.replace(
-        base, num_layers=layers, d_model=128, num_heads=4, num_kv_heads=4,
-        d_ff=512, vocab_size=VOCAB,
-        num_classes=DOMAINS[calib_domain].num_classes, dtype="float32")
-    train_data = make_dataset(calib_domain, n_train, seed=seed)
+    the serving phase needs. ``cfg`` defaults to the CPU-sized
+    ``testbed_config(layers=layers)``."""
+    if cfg is None:
+        cfg = testbed_config(layers=layers, calib_domain=calib_domain)
+    train_data = make_dataset(calib_domain, n_train, seed=seed,
+                              seq_len=seq_len)
     params, model, log = train_classifier(cfg, train_data, steps=steps,
-                                          batch_size=64, seed=seed)
-    eval_data = make_dataset(eval_domain, n_eval, seed=seed + 1)
+                                          batch_size=batch_size, seed=seed)
+    eval_data = make_dataset(eval_domain, n_eval, seed=seed + 1,
+                             seq_len=seq_len)
     # alpha calibrated on the *fine-tune* domain validation slice (labeled)
-    val = make_dataset(calib_domain, 1024, seed=seed + 2)
+    val = make_dataset(calib_domain, 1024, seed=seed + 2, seq_len=seq_len)
     conf_val, _, correct_val = exit_accuracy(model, params, val)
     return cfg, params, model, train_data, eval_data, (conf_val,
                                                        correct_val), log
@@ -318,7 +347,13 @@ def main():
     ap = argparse.ArgumentParser()
     add_serving_config_args(ap)
     # testbed / cluster-shape flags (not part of the ServingConfig)
-    ap.add_argument("--layers", type=int, default=6)
+    ap.add_argument("--published", action="store_true",
+                    help="train and serve elasticbert12 at its published "
+                         "widths (12 x 768, vocab 30522) on "
+                         f"{PUBLISHED_SEQ_LEN}-token inputs instead of the "
+                         "CPU-sized testbed")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="testbed depth (default 6; 12 with --published)")
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--offload", type=float, default=5.0)
     ap.add_argument("--eval-domain", default="imdb_like")
@@ -340,6 +375,7 @@ def main():
     args = ap.parse_args()
 
     scfg = serving_config_from_args(args)
+    enable_compile_cache()
 
     # worker mode iff the SPLITEE_* cluster env vars are present (set by
     # respawn_distributed); must run before any other jax use
@@ -387,8 +423,11 @@ def main():
     host0 = (not in_cluster) or cluster_identity()[0] == 0
 
     cfg, params, model, _, eval_data, (conf_val, correct_val), log = \
-        build_testbed(layers=args.layers, steps=args.steps,
-                      eval_domain=args.eval_domain)
+        build_testbed(
+            cfg=testbed_config(published=args.published, layers=args.layers),
+            steps=args.steps, eval_domain=args.eval_domain,
+            seq_len=PUBLISHED_SEQ_LEN if args.published else SEQ_LEN,
+            batch_size=PUBLISHED_TRAIN_BATCH if args.published else 64)
     if host0:
         print(f"trained multi-exit testbed: final loss {log[-1]['loss']:.4f}")
 

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.data import batch_iterator, make_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import Model, build_model
 from repro.optim import adamw_init, adamw_update
 from repro.optim.adamw import AdamWConfig
@@ -86,6 +87,7 @@ def main():
     ap.add_argument("--domain", default="sst2_like")
     ap.add_argument("--n-train", type=int, default=8192)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.num_classes == 0:

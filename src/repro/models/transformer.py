@@ -311,22 +311,32 @@ def forward_exits(params, cfg: ModelConfig, batch: Dict[str, Any], *,
         body, (x, jnp.zeros((), jnp.float32)), (params["layers"], idx),
         unroll=_unroll())
     # pooled: (L, B, D)
-    l, bb, d = pooled.shape
+    conf, pred = stacked_exit_confidence(params, cfg, pooled,
+                                         conf_backend=conf_backend)
+    return {"conf": conf, "pred": pred, "hidden": x}
+
+
+def _exit_heads(params, cfg: ModelConfig):
+    """Every exit's head: the shared (D, V) matrix, or the per-layer
+    (L, D, V) stack."""
     if cfg.exits.share_head or not cfg.exits.enabled:
-        conf, pred = exit_confidence(
-            pooled.reshape(l * bb, d), params["exit_w"],
-            backend=conf_backend)
-    else:
-        ews = params["layers"]["exit_w"]                 # (L, D, C) stacked
-        def per_exit(p_i, w_i):
-            return exit_confidence(p_i, w_i, backend=conf_backend)
-        conf, pred = jax.vmap(per_exit)(pooled, ews)
-        conf, pred = conf.reshape(l * bb), pred.reshape(l * bb)
-    return {
-        "conf": conf.reshape(l, bb),
-        "pred": pred.reshape(l, bb),
-        "hidden": x,
-    }
+        return params["exit_w"]
+    return params["layers"]["exit_w"]
+
+
+def stacked_exit_confidence(params, cfg: ModelConfig, pooled, *,
+                            conf_backend: str = "ref",
+                            fused_exit: bool = False):
+    """(conf, pred), each (L, B), of all L exits from their pooled hidden
+    ``pooled (L, B, D)`` — one confidence launch for every exit. With
+    ``fused_exit`` the pools are RAW and each layer's exit norm runs
+    inside the launch; otherwise they are already normed."""
+    ews = _exit_heads(params, cfg)
+    if fused_exit:
+        return exit_confidence_fused(pooled, params["layers"]["exit_norm"],
+                                     ews, kind=cfg.norm,
+                                     backend=conf_backend)
+    return exit_confidence(pooled, ews, backend=conf_backend)
 
 
 def forward_exits_masked(params, cfg: ModelConfig, batch: Dict[str, Any],
@@ -379,40 +389,10 @@ def forward_exits_masked(params, cfg: ModelConfig, batch: Dict[str, Any],
     x, pooled = jax.lax.scan(body, x, (params["layers"], idx),
                              unroll=_unroll())
     # pooled: (L, B, D) — per-layer exit pools, frozen past each depth
-    l, bb, d = pooled.shape
-    share = cfg.exits.share_head or not cfg.exits.enabled
-    if fused_exit:
-        norm_p = params["layers"]["exit_norm"]   # stacked (L, D) entries
-        if share:
-            # rows are (l*bb, d) with row l*bb+b normed by layer l's exit
-            # norm -> repeat each layer's params bb times row-wise
-            rows_p = jax.tree.map(lambda a: jnp.repeat(a, bb, axis=0),
-                                  norm_p)
-            conf, pred = exit_confidence_fused(pooled.reshape(l * bb, d),
-                                               rows_p, params["exit_w"],
-                                               kind=cfg.norm,
-                                               backend=conf_backend)
-        else:
-            conf, pred = jax.vmap(
-                lambda p_i, np_i, w_i: exit_confidence_fused(
-                    p_i, np_i, w_i, kind=cfg.norm, backend=conf_backend))(
-                pooled, norm_p, params["layers"]["exit_w"])
-            conf, pred = conf.reshape(l * bb), pred.reshape(l * bb)
-    elif share:
-        conf, pred = exit_confidence(pooled.reshape(l * bb, d),
-                                     params["exit_w"],
-                                     backend=conf_backend)
-    else:
-        conf, pred = jax.vmap(
-            lambda p_i, w_i: exit_confidence(p_i, w_i,
-                                             backend=conf_backend))(
-            pooled, params["layers"]["exit_w"])
-        conf, pred = conf.reshape(l * bb), pred.reshape(l * bb)
-    return {
-        "conf": conf.reshape(l, bb),
-        "pred": pred.reshape(l, bb),
-        "hidden": x,
-    }
+    conf, pred = stacked_exit_confidence(params, cfg, pooled,
+                                         conf_backend=conf_backend,
+                                         fused_exit=fused_exit)
+    return {"conf": conf, "pred": pred, "hidden": x}
 
 
 # ----------------------------------------------------------- prefill / decode
@@ -565,17 +545,9 @@ def decode_step(params, cfg: ModelConfig, caches, token_or_embed,
         ew = params["exit_w"]
     else:
         ew = params["layers"]["exit_w"][-1]              # final exit's head
-    l, bb, d = pooled.shape
     if all_exits:
-        if shared:
-            conf, pred = exit_confidence(pooled.reshape(l * bb, d), ew,
-                                         backend=conf_backend)
-        else:
-            conf, pred = jax.vmap(
-                lambda p_i, w_i: exit_confidence(
-                    p_i, w_i, backend=conf_backend))(
-                pooled, params["layers"]["exit_w"])
-        conf, pred = conf.reshape(l, bb), pred.reshape(l, bb)
+        conf, pred = stacked_exit_confidence(params, cfg, pooled,
+                                             conf_backend=conf_backend)
     elif split_layer is not None:
         h_split = jax.lax.dynamic_index_in_dim(pooled, split_layer, 0,
                                                keepdims=False)
@@ -689,20 +661,10 @@ def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
             unroll=_unroll())
         new_caches = {cache_key: new_st}
 
-    shared = cfg.exits.share_head or not cfg.exits.enabled
-    if shared:
-        ew = params["exit_w"]
-        l, bb, d = pooled.shape
-        conf, pred = exit_confidence(pooled.reshape(l * bb, d), ew,
-                                     backend=conf_backend)
-    else:
-        ew = params["layers"]["exit_w"][-1]
-        l, bb, d = pooled.shape
-        conf, pred = jax.vmap(
-            lambda p_i, w_i: exit_confidence(
-                p_i, w_i, backend=conf_backend))(
-            pooled, params["layers"]["exit_w"])
-    conf, pred = conf.reshape(l, bb), pred.reshape(l, bb)
+    conf, pred = stacked_exit_confidence(params, cfg, pooled,
+                                         conf_backend=conf_backend)
+    ews = _exit_heads(params, cfg)
+    ew = ews if ews.ndim == 2 else ews[-1]           # final exit's head
 
     xf = apply_norm(x, params["final_norm"], cfg.norm)
     logits = constrain(xf[:, -1, :] @ ew, "batch", "model")

@@ -1094,6 +1094,33 @@ class _Worker:
         return now - max(mtime, self.spawned_wall) > watchdog_timeout
 
 
+def refuse_shared_chip(num_processes: int,
+                       env: Optional[Dict[str, str]] = None) -> None:
+    """Refuse to start workers that would all open this host's TPU chip.
+
+    Every spawned worker inherits the parent's view of the host, so on a
+    chip host each would try to load the TPU: the first holds it and the
+    rest block on or fail at libtpu's lock. Multi-process serving needs
+    one chip per process, which these drivers do not assign. Workers
+    pinned to the CPU (``JAX_PLATFORMS=cpu``) are fine. The chip count
+    comes from the PCI bus, so the check opens no device."""
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+
+    platforms = (env or {}).get("JAX_PLATFORMS",
+                                os.environ.get("JAX_PLATFORMS", ""))
+    if num_processes < 2 or platforms == "cpu":
+        return
+    chips, _ = num_available_tpu_chips_and_device_id()
+    if chips:
+        raise RuntimeError(
+            f"multi-process serving needs one chip per process: all "
+            f"{num_processes} workers would open the same {chips} TPU "
+            f"chip(s) of this host, and a chip belongs to one process at "
+            f"a time. Serve from one process instead (--mesh --replicas "
+            f"N drives every local chip), or run a CPU cluster with "
+            f"JAX_PLATFORMS=cpu.")
+
+
 def run_supervised_cluster(
         worker_src: str, num_processes: int, *,
         devices_per_process: int = 1, env: Optional[Dict[str, str]] = None,
@@ -1135,7 +1162,11 @@ def run_supervised_cluster(
                           `ENV_REJOIN` set, so it takes the rejoin path
                           and re-enters the cluster at an epoch
                           boundary from the KV-store state.
+
+    Raises RuntimeError before spawning anything when the workers would
+    share this host's TPU chip (`refuse_shared_chip`).
     """
+    refuse_shared_chip(num_processes, env)
     port = _free_port() if coordinator else None
     hb_dir = (tempfile.mkdtemp(prefix="splitee-hb-")
               if watchdog_timeout is not None else None)

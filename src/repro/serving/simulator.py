@@ -34,7 +34,7 @@ from repro.kernels.exit_confidence.ops import (exit_confidence,
 from repro.models.common import apply_norm
 from repro.models.transformer import (_exit_w, _layer_full, _positions,
                                       embed_inputs, forward_exits_masked,
-                                      pool_hidden)
+                                      pool_hidden, stacked_exit_confidence)
 from repro.serving.offload_codec import OffloadCodec
 
 
@@ -120,38 +120,10 @@ class EdgeCloudRuntime:
 
             idx = jnp.arange(cfg.num_layers)
             x, pooled = jax.lax.scan(body, x, (params["layers"], idx))
-            l, bb, d = pooled.shape
-            share = cfg.exits.share_head or not cfg.exits.enabled
-            if fused_exit:
-                # raw pooled rows (l*bb, d); row l*bb+b normalizes with
-                # layer l's exit norm, so repeat each (D,) scale bb times
-                norm_p = params["layers"]["exit_norm"]
-                rows_p = jax.tree.map(lambda a: jnp.repeat(a, bb, axis=0),
-                                      norm_p)
-                if share:
-                    conf, pred = exit_confidence_fused(
-                        pooled.reshape(l * bb, d), rows_p,
-                        params["exit_w"], kind=cfg.norm,
-                        backend=conf_backend)
-                else:
-                    conf, pred = jax.vmap(
-                        lambda p, npar, wl: exit_confidence_fused(
-                            p, npar, wl, kind=cfg.norm,
-                            backend=conf_backend))(
-                        pooled, norm_p, params["layers"]["exit_w"])
-                    conf, pred = conf.reshape(l * bb), pred.reshape(l * bb)
-            elif share:
-                conf, pred = exit_confidence(pooled.reshape(l * bb, d),
-                                             params["exit_w"],
-                                             backend=conf_backend)
-            else:
-                conf, pred = jax.vmap(
-                    lambda p, wl: exit_confidence(
-                        p, wl, backend=conf_backend))(
-                    pooled, params["layers"]["exit_w"])
-                conf, pred = conf.reshape(l * bb), pred.reshape(l * bb)
-            x_at_depth = None  # S-variant offloads from `depth` too
-            return conf.reshape(l, bb), pred.reshape(l, bb), x
+            conf, pred = stacked_exit_confidence(
+                params, cfg, pooled, conf_backend=conf_backend,
+                fused_exit=fused_exit)
+            return conf, pred, x
 
         @jax.jit
         def edge_scan_fn(params, batch, depths):

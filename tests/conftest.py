@@ -1,13 +1,22 @@
 import dataclasses
+import os
 
-import jax
-import pytest
+# Tier-1 runs on the CPU: a chip belongs to one process at a time and the
+# suite runs in several workers, so no test may take an attached TPU.
+# Set before jax is imported; an explicit JAX_PLATFORMS still wins.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-# NOTE: no XLA_FLAGS here — smoke tests and benches must see ONE device.
-# Only launch/dryrun.py forces the 512-device placeholder topology.
-# Lock the single-device backend NOW, before any test module import can
-# side-effect XLA_FLAGS (test_dryrun_unit imports launch.dryrun):
-assert len(jax.devices()) >= 1
+import jax  # noqa: E402
+import pytest  # noqa: E402
+
+# Smoke tests and benches must see ONE host device; the multi-device
+# tests force more in their own subprocesses. This reads the environment
+# only (no backend is initialised here), so every worker collects alike.
+if "xla_force_host_platform_device_count" in os.environ.get("XLA_FLAGS", ""):
+    raise pytest.UsageError(
+        "tier-1 expects one host device: unset "
+        "--xla_force_host_platform_device_count in XLA_FLAGS (the "
+        "multi-device tests set it for their own subprocesses)")
 
 
 @pytest.fixture(scope="session")

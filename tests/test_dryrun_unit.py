@@ -7,9 +7,8 @@ import pytest
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config
 from repro.models.api import build_model
 
-# import parse/_with_depth without triggering the XLA_FLAGS (module sets
-# env var, harmless under an already-initialized single-device runtime
-# as long as jax was already imported — which pytest conftest guarantees)
+# importing the dry-run module must not force its 512 host devices: it
+# sets XLA_FLAGS only when run as a script
 from repro.launch.dryrun import _with_depth, parse_collective_bytes
 
 
@@ -22,6 +21,11 @@ HloModule test
   %p = f32[8]{0} collective-permute(%f)
   %n = f32[8,8]{1,0} add(%g, %h)
 """
+
+
+def test_import_does_not_force_host_devices():
+    import os
+    assert "device_count=512" not in os.environ.get("XLA_FLAGS", "")
 
 
 def test_parse_collective_bytes():

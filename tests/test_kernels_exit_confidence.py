@@ -160,20 +160,79 @@ def test_fused_matches_ref(b, d, v, kind, with_bias):
 
 @pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
 def test_fused_per_row_norm_params(kind):
-    """The scan path stacks per-layer exit norms row-wise: norm params of
-    shape (B, D) apply row b's gamma/beta to row b."""
+    """Norm params of shape (B, D) apply row b's gamma/beta to row b when
+    each row is its own head (x (B, 1, D)); a single head refuses them."""
     b, d, v = 6, 32, 65
     key = jax.random.PRNGKey(11)
     x = jax.random.normal(key, (b, d))
     w = jax.random.normal(jax.random.fold_in(key, 1), (d, v)) * 0.2
     npar = _norm_params(jax.random.fold_in(key, 3), d, kind, rows=b)
-    c0, p0 = exit_confidence_fused(x, npar, w, kind=kind, backend="ref")
-    c1, p1 = exit_confidence_fused(x, npar, w, kind=kind,
-                                   backend="pallas_interpret", block_b=4,
-                                   block_v=32)
-    np.testing.assert_allclose(np.asarray(c0), np.asarray(c1), rtol=2e-5,
-                               atol=2e-6)
-    assert (np.asarray(p0) == np.asarray(p1)).all()
+    want = [exit_confidence_fused(x[i:i + 1],
+                                  {k: a[i] for k, a in npar.items()}, w,
+                                  kind=kind, backend="ref")
+            for i in range(b)]
+    c0 = np.concatenate([np.asarray(c) for c, _ in want])
+    p0 = np.concatenate([np.asarray(p) for _, p in want])
+    for backend in ("ref", "pallas_interpret"):
+        c1, p1 = exit_confidence_fused(x[:, None], npar, w, kind=kind,
+                                       backend=backend, block_v=32)
+        np.testing.assert_allclose(c0, np.asarray(c1)[:, 0], rtol=2e-5,
+                                   atol=2e-6)
+        assert (p0 == np.asarray(p1)[:, 0]).all()
+        with pytest.raises(ValueError, match="per-head norm params"):
+            exit_confidence_fused(x, npar, w, kind=kind, backend=backend)
+
+
+STACKED = [(3, 5, 32, 2), (4, 9, 48, 70), (2, 16, 64, 300)]
+
+
+@pytest.mark.parametrize("g,b,d,v", STACKED)
+@pytest.mark.parametrize("shared_head", [False, True])
+@pytest.mark.parametrize("kind", [None, "rmsnorm", "layernorm"])
+def test_stacked_heads_match_per_head_ref(g, b, d, v, shared_head, kind):
+    """G exit heads scored by one launch equal G single-head oracle
+    calls: per-head (G, D, V) or shared (D, V) weights, per-head (G, D)
+    norm params, batches that are not a multiple of the 8-row tile."""
+    key = jax.random.PRNGKey(g * 100 + b + v)
+    x = jax.random.normal(key, (g, b, d))
+    w = jax.random.normal(jax.random.fold_in(key, 1),
+                          (d, v) if shared_head else (g, d, v)) * 0.2
+    npar = (None if kind is None else
+            _norm_params(jax.random.fold_in(key, 3), d, kind, rows=g))
+
+    def one_head(i, backend, **kw):
+        wi = w if shared_head else w[i]
+        if kind is None:
+            return exit_confidence(x[i], wi, backend=backend, **kw)
+        pi = {k: a[i] for k, a in npar.items()}
+        return exit_confidence_fused(x[i], pi, wi, kind=kind,
+                                     backend=backend, **kw)
+
+    def all_heads(backend, **kw):
+        if kind is None:
+            return exit_confidence(x, w, backend=backend, **kw)
+        return exit_confidence_fused(x, npar, w, kind=kind,
+                                     backend=backend, **kw)
+
+    want = [one_head(i, "ref") for i in range(g)]
+    c0 = np.stack([np.asarray(c) for c, _ in want])
+    p0 = np.stack([np.asarray(p) for _, p in want])
+    for backend, kw in (("ref", {}),
+                        ("pallas_interpret", dict(block_b=8, block_v=128))):
+        c1, p1 = all_heads(backend, **kw)
+        assert c1.shape == p1.shape == (g, b)
+        np.testing.assert_allclose(c0, np.asarray(c1), rtol=2e-5, atol=2e-6)
+        assert (p0 == np.asarray(p1)).all()
+
+
+def test_stacked_heads_are_one_launch():
+    """The per-exit heads go through ONE pallas_call, not a vmap over it
+    (which the TPU compiler refuses)."""
+    x = jnp.ones((12, 4, 16))
+    w = jnp.ones((12, 16, 2))
+    jx = jax.make_jaxpr(lambda x, w: exit_confidence(
+        x, w, backend="pallas_interpret"))(x, w).jaxpr
+    assert _count(jx, "pallas_call") == 1
 
 
 def test_fused_ref_equals_unfused_compose():
