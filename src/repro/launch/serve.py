@@ -280,6 +280,22 @@ def serving_config_from_args(args) -> ServingConfig:
 DECODE_EXIT_RATE = 0.85     # alpha-calibration target: shallow-exit freq
 
 
+def print_phases(telemetry) -> None:
+    """The session's per-phase self time (``ServeReport.telemetry``),
+    largest first, and its counters."""
+    if not telemetry or not telemetry["spans"]:
+        return
+    print("phases (ms; self time excludes child phases):")
+    spans = sorted(telemetry["spans"].items(),
+                   key=lambda kv: -kv[1]["self_ms"])
+    for name, s in spans:
+        print(f"  {name:<28} n={s['n']:<7} self={s['self_ms']:10.2f} "
+              f"total={s['total_ms']:10.2f}")
+    if telemetry["counts"]:
+        print("  counts: " + " ".join(
+            f"{k}={v}" for k, v in telemetry["counts"].items()))
+
+
 def run_decode(args, scfg: ServingConfig):
     """Decode workload: stream prompts through the per-token early-exit
     runtime (serving/decode.py). There is no LM fine-tuning stage in this
@@ -341,6 +357,7 @@ def run_decode(args, scfg: ServingConfig):
         s = out.scheduler
         print(f"scheduler: served={s['served']} shed={s['shed']} "
               f"{dict(s['shed_reasons'])}")
+    print_phases(out.telemetry)
 
 
 def main():
@@ -502,6 +519,7 @@ def main():
               f"p50={lat.get('p50', float('nan')):.2f}ms "
               f"p99={lat.get('p99', float('nan')):.2f}ms "
               f"fill={fill if fill is None else round(fill, 2)}")
+    print_phases(out.telemetry)
 
     if skip:
         return     # rejoined host 0: partial stream, baselines unmeaning

@@ -638,9 +638,10 @@ def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
             return (xx, occ), (new_st, pooled)
 
         idx = jnp.arange(cfg.num_layers)
-        (x, occ), (new_ssm, pooled) = jax.lax.scan(
-            body, (x, caches["attn"]), (params["layers"], caches["ssm"], idx),
-            unroll=_unroll())
+        with jax.named_scope("splitee.layers"):
+            (x, occ), (new_ssm, pooled) = jax.lax.scan(
+                body, (x, caches["attn"]),
+                (params["layers"], caches["ssm"], idx), unroll=_unroll())
         new_caches = {"ssm": new_ssm, "attn": occ}
     else:
         cache_key = "ssm" if cfg.family == "ssm" else "attn"
@@ -656,18 +657,20 @@ def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
             return xx, (new_st, pooled)
 
         idx = jnp.arange(cfg.num_layers)
-        x, (new_st, pooled) = jax.lax.scan(
-            body, x, (params["layers"], caches[cache_key], idx),
-            unroll=_unroll())
+        with jax.named_scope("splitee.layers"):
+            x, (new_st, pooled) = jax.lax.scan(
+                body, x, (params["layers"], caches[cache_key], idx),
+                unroll=_unroll())
         new_caches = {cache_key: new_st}
 
-    conf, pred = stacked_exit_confidence(params, cfg, pooled,
-                                         conf_backend=conf_backend)
-    ews = _exit_heads(params, cfg)
-    ew = ews if ews.ndim == 2 else ews[-1]           # final exit's head
-
-    xf = apply_norm(x, params["final_norm"], cfg.norm)
-    logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
+    with jax.named_scope("splitee.exit_heads"):
+        conf, pred = stacked_exit_confidence(params, cfg, pooled,
+                                             conf_backend=conf_backend)
+    with jax.named_scope("splitee.final_head"):
+        ews = _exit_heads(params, cfg)
+        ew = ews if ews.ndim == 2 else ews[-1]       # final exit's head
+        xf = apply_norm(x, params["final_norm"], cfg.norm)
+        logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
     return logits, conf, pred, x, new_caches
 
 
@@ -724,9 +727,10 @@ def decode_step_resume(params, cfg: ModelConfig, caches, hidden,
             return (xx, occ), new_st
 
         idx = jnp.arange(cfg.num_layers)
-        (x, occ), new_ssm = jax.lax.scan(
-            body, (x, caches["attn"]), (params["layers"], caches["ssm"], idx),
-            unroll=_unroll())
+        with jax.named_scope("splitee.layers"):
+            (x, occ), new_ssm = jax.lax.scan(
+                body, (x, caches["attn"]),
+                (params["layers"], caches["ssm"], idx), unroll=_unroll())
         new_caches = {"ssm": new_ssm, "attn": occ}
     else:
         cache_key = "ssm" if cfg.family == "ssm" else "attn"
@@ -741,15 +745,17 @@ def decode_step_resume(params, cfg: ModelConfig, caches, hidden,
             return xx, new_st
 
         idx = jnp.arange(cfg.num_layers)
-        x, new_st = jax.lax.scan(
-            body, x, (params["layers"], caches[cache_key], idx),
-            unroll=_unroll())
+        with jax.named_scope("splitee.layers"):
+            x, new_st = jax.lax.scan(
+                body, x, (params["layers"], caches[cache_key], idx),
+                unroll=_unroll())
         new_caches = {cache_key: new_st}
 
-    ew = params["exit_w"] if "exit_w" in params \
-        else params["layers"]["exit_w"][-1]
-    xf = apply_norm(x, params["final_norm"], cfg.norm)
-    logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
+    with jax.named_scope("splitee.final_head"):
+        ew = params["exit_w"] if "exit_w" in params \
+            else params["layers"]["exit_w"][-1]
+        xf = apply_norm(x, params["final_norm"], cfg.norm)
+        logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
     return logits, new_caches
 
 

@@ -52,6 +52,7 @@ from repro.serving.scheduler import (SCHEDULERS, SHED_POLICIES,
                                      RequestScheduler)
 from repro.serving.sharded import _ShardedSession, _serve_stream_sharded
 from repro.serving.simulator import EdgeCloudRuntime, _serve_stream_sequential
+from repro.serving.tracing import Tracer
 
 PATHS = ("auto", "sequential", "batched", "sharded", "distributed")
 EDGE_MODES = ("bucketed", "scan", "auto")
@@ -460,6 +461,7 @@ class ServeReport:
     scheduler: Optional[Dict[str, Any]] = None     # request-scheduler stats
     decode: Optional[Dict[str, Any]] = None        # decode-workload section
     tenant: Optional[str] = None                   # MultiTenantEngine label
+    telemetry: Optional[Dict[str, Any]] = None     # session Tracer totals
 
     @classmethod
     def from_raw(cls, raw: Dict[str, Any], *, path: str, num_layers: int,
@@ -500,6 +502,7 @@ class ServeReport:
             scheduler=raw.get("scheduler"),
             decode=raw.get("decode"),
             tenant=raw.get("tenant"),
+            telemetry=raw.get("telemetry"),
         )
 
     def to_dict(self) -> Dict[str, Any]:
@@ -729,6 +732,13 @@ def _build_session(runtime, params, cost: CostModel, config: ServingConfig,
     return sess, path
 
 
+def _note_queue_wait(tracer: Tracer, reqs, now: float) -> None:
+    """Each request's wait from its arrival to the formation of the batch
+    that takes it, on the scheduler's clock."""
+    for r in reqs:
+        tracer.add("splitee.sched.queue_wait", (now - r.arrival) * 1e9)
+
+
 class Engine:
     """Push-session serving: request-level traffic over the same
     controller/queue machinery as the one-shot `serve()` facade.
@@ -899,9 +909,17 @@ class Engine:
             return 0
         return self._pump()
 
-    def _pump(self) -> int:
+    def _pump(self, flush: bool = False) -> int:
+        """Form the batches that are ready (every queued request with
+        ``flush``) and push them through the session."""
+        tr = self._sess.tracer
+        now = self._sched.clock()
+        with tr.span("splitee.sched.form"):
+            batches = (self._sched.flush(now) if flush
+                       else self._sched.poll(now))
         served = 0
-        for reqs in self._sched.poll():
+        for reqs in batches:
+            _note_queue_wait(tr, reqs, now)
             self._sess.push([r.sample for r in reqs])
             self._sched.complete(reqs)
             served += len(reqs)
@@ -915,9 +933,7 @@ class Engine:
         if self._closed:
             raise RuntimeError("Engine is closed; create a new session")
         if self._sched is not None:
-            for reqs in self._sched.flush():
-                self._sess.push([r.sample for r in reqs])
-                self._sched.complete(reqs)
+            self._pump(flush=True)
         elif self._buf:
             self._sess.push(self._buf)
             self._buf = []
@@ -1077,10 +1093,16 @@ class MultiTenantEngine:
                 "MultiTenantEngine is closed; create a new one")
         return self._pump()
 
-    def _pump(self) -> int:
+    def _pump(self, flush: bool = False) -> int:
+        """Form the ready batches (every queued request with ``flush``)
+        and push each through its tenant's session."""
+        now = self._sched.clock()
+        batches = self._sched.flush(now) if flush else self._sched.poll(now)
         served = 0
-        for reqs in self._sched.poll():
-            self._sessions[reqs[0].tenant].push([r.sample for r in reqs])
+        for reqs in batches:
+            sess = self._sessions[reqs[0].tenant]
+            _note_queue_wait(sess.tracer, reqs, now)
+            sess.push([r.sample for r in reqs])
             self._sched.complete(reqs)
             served += len(reqs)
         return served
@@ -1090,9 +1112,7 @@ class MultiTenantEngine:
         session, and return per-tenant reports. Idempotent."""
         if self._closed:
             return self._final
-        for reqs in self._sched.flush():
-            self._sessions[reqs[0].tenant].push([r.sample for r in reqs])
-            self._sched.complete(reqs)
+        self._pump(flush=True)
         wall = time.perf_counter() - self._t0
         snap = self._sched.snapshot()
         per_tenant = snap.get("tenants", {})

@@ -43,6 +43,7 @@ from repro.core.rewards import CostModel
 from repro.data.stream import microbatches
 from repro.serving.offload_codec import OffloadCodec
 from repro.serving.simulator import EdgeCloudRuntime
+from repro.serving.tracing import Tracer
 
 
 def _pow2(k: int) -> int:
@@ -305,6 +306,7 @@ class _BatchedSession:
             {"conf_path": [], "conf_L": []} if record_trace else None)
         self.n = 0
         self.batch_sizes: List[int] = []   # fill levels of pushed batches
+        self.tracer = Tracer()
 
     def push(self, batch):
         """Serve one micro-batch (any size >= 1; ragged tails included).
@@ -313,43 +315,56 @@ class _BatchedSession:
         if not batch:
             return
         B = len(batch)
-        self.batch_sizes.append(B)
-        arms = self.ctl.choose_splits(B)
-        tokens = np.stack([np.asarray(s["tokens"]) for s in batch])
-        seq_len = tokens.shape[1]
+        tr = self.tracer
+        with tr.span("splitee.batched.push"):
+            tr.count("splitee.batched.batches")
+            tr.count("splitee.batched.rows", B)
+            self.batch_sizes.append(B)
+            with tr.span("splitee.batched.select"):
+                arms = self.ctl.choose_splits(B)
+                tokens = np.stack([np.asarray(s["tokens"]) for s in batch])
+            seq_len = tokens.shape[1]
 
-        # ---- edge: per-depth bucket launches, or one masked scan -------
-        conf_paths, batch_preds = self._edge_phase(
-            self.runtime, self.params, tokens, arms, self.cost, self.queue,
-            side_info=self.side_info)
+            # ---- edge: per-depth bucket launches, or one masked scan ---
+            with tr.span("splitee.batched.edge"):
+                conf_paths, batch_preds = self._edge_phase(
+                    self.runtime, self.params, tokens, arms, self.cost,
+                    self.queue, side_info=self.side_info)
 
-        # ---- cloud: flush the offload queue in depth buckets -----------
-        pending = self.queue.flush_async()
-        cloud = pending.resolve()
-        conf_Ls: List[Optional[float]] = [None] * B
-        obs = [0] * B
-        for s, (c_L, p_L) in cloud.items():
-            conf_Ls[s] = c_L
-            batch_preds[s] = p_L
-            # bytes the flush actually shipped for this slot (codec wire
-            # format when one is set, raw activation bytes otherwise)
-            obs[s] = pending.slot_bytes[s]
+            # ---- cloud: flush the offload queue in depth buckets -------
+            with tr.span("splitee.batched.cloud"):
+                # one cloud_fn launch per queued depth
+                tr.count("splitee.batched.cloud_launches",
+                         len(self.queue.rows))
+                pending = self.queue.flush_async()
+                cloud = pending.resolve()
 
-        # ---- delayed-feedback batch update -----------------------------
-        self.ctl.update_batch(
-            arms, conf_paths, conf_Ls, obs,
-            offload_scale=_offload_scale(self.codec, self.runtime, seq_len))
+            # ---- delayed-feedback batch update -------------------------
+            with tr.span("splitee.batched.fold"):
+                conf_Ls: List[Optional[float]] = [None] * B
+                obs = [0] * B
+                for s, (c_L, p_L) in cloud.items():
+                    conf_Ls[s] = c_L
+                    batch_preds[s] = p_L
+                    # bytes the flush actually shipped for this slot
+                    # (codec wire format when one is set, raw activation
+                    # bytes otherwise)
+                    obs[s] = pending.slot_bytes[s]
+                self.ctl.update_batch(
+                    arms, conf_paths, conf_Ls, obs,
+                    offload_scale=_offload_scale(self.codec, self.runtime,
+                                                 seq_len))
 
-        self.preds.extend(batch_preds)
-        if self.trace is not None:
-            self.trace["conf_path"].extend(conf_paths)
-            self.trace["conf_L"].extend(conf_Ls)
-        if self.labels_for_accounting:
-            for s, sample in enumerate(batch):
-                if "labels" in sample:
-                    self.correct.append(
-                        int(batch_preds[s] == int(sample["labels"])))
-        self.n += B
+                self.preds.extend(batch_preds)
+                if self.trace is not None:
+                    self.trace["conf_path"].extend(conf_paths)
+                    self.trace["conf_L"].extend(conf_Ls)
+                if self.labels_for_accounting:
+                    for s, sample in enumerate(batch):
+                        if "labels" in sample:
+                            self.correct.append(
+                                int(batch_preds[s] == int(sample["labels"])))
+                self.n += B
 
     def drain(self):
         """Synchronous path: every flush resolved at its own boundary —
@@ -374,6 +389,7 @@ class _BatchedSession:
             "rewards": hist["reward"],
             "exited": hist["exited"],
             "state": ctl.snapshot(),
+            "telemetry": self.tracer.snapshot(),
         }
         if self.correct:
             out["accuracy"] = float(np.mean(self.correct))

@@ -39,7 +39,6 @@ both uniformly.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -53,6 +52,7 @@ from repro.data.stream import microbatches
 from repro.models import transformer
 from repro.serving.kvcache import DecodeCacheManager, offload_scale_vec
 from repro.serving.offload_codec import OffloadCodec
+from repro.serving.tracing import Tracer
 
 PyTree = Any
 
@@ -82,11 +82,15 @@ class DecodeRuntime:
                 "decode serving is token-in/token-out; stub-modality archs"
                 " are not supported")
 
+        # the named scopes name each device op's program in a profiler
+        # trace (metadata only: the numbers do not change)
+        @jax.named_scope("splitee.prefill")
         def _prefill(params, tokens, cache_seq_len):
             return transformer.prefill(
                 params, cfg, {"tokens": tokens}, backend=self.backend,
                 cache_seq_len=cache_seq_len)
 
+        @jax.named_scope("splitee.edge")
         def _edge(params, caches, token, cur_index, depths, window_seq_len):
             logits, conf, pred, hidden, new_caches = \
                 transformer.decode_step_masked(
@@ -99,6 +103,7 @@ class DecodeRuntime:
             return (logits, conf, pred, conf_fin, pred_fin, hidden,
                     new_caches)
 
+        @jax.named_scope("splitee.cloud")
         def _cloud(params, caches, hidden, cur_index, depths, active,
                    window_seq_len):
             logits, new_caches = transformer.decode_step_resume(
@@ -122,7 +127,9 @@ class _DecodeSession:
     (select → masked edge → per-sample exit/offload → blocking cloud
     resume for the offloaders → vectorized fold). The prefill's argmax is
     round 0's input token; generated tokens are the rounds' outputs.
-    `result()` is non-destructive and adds a ``decode`` section.
+    `result()` is non-destructive and adds a ``decode`` section and the
+    session tracer's ``telemetry`` (serving/tracing.py), whose
+    ``splitee.decode.push`` totals give ``decode_wall_s``.
     """
 
     def __init__(self, runtime: DecodeRuntime, params, cost: CostModel, *,
@@ -152,7 +159,7 @@ class _DecodeSession:
         self._scale = (offload_scale_vec(runtime.cfg, codec)
                        if codec is not None else 1.0)
         self.n = 0
-        self._wall = 0.0
+        self.tracer = Tracer()
         self._pushes: List[Dict[str, Any]] = []
         self._exits_hist = np.zeros((max_new_tokens, cost.num_layers),
                                     np.int64)
@@ -174,83 +181,23 @@ class _DecodeSession:
         S = prompts.shape[1]
         T = self.max_new_tokens
         total = S + T
-        L = self.cost.num_layers
         cfg = self.runtime.cfg
 
-        t0 = time.perf_counter()
-        logits0, caches = self.runtime.prefill_fn(
-            self.params, jnp.asarray(prompts), total)
-        mgr = DecodeCacheManager(cfg, caches, codec=self.codec)
-        tok = jnp.argmax(logits0, -1).astype(jnp.int32)
+        tr = self.tracer
+        with tr.span("splitee.decode.push", push=len(self._pushes)):
+            with tr.span("splitee.decode.prefill"):
+                logits0, caches = self.runtime.prefill_fn(
+                    self.params, jnp.asarray(prompts), total)
+                mgr = DecodeCacheManager(cfg, caches, codec=self.codec)
+                tok = jnp.argmax(logits0, -1).astype(jnp.int32)
+            gen = np.zeros((B, T), np.int32)
+            exited_steps = np.zeros((T, B), bool)
+            for t in range(T):
+                with tr.span("splitee.decode.step", push=len(self._pushes),
+                             step=t):
+                    tok = self._round(mgr, tok, S + t, total, t, gen,
+                                      exited_steps)
 
-        gen = np.zeros((B, T), np.int32)
-        exited_steps = np.zeros((T, B), bool)
-        for t in range(T):
-            if self.split_policy == "final":
-                arms = np.full(B, L - 1, np.int64)
-            else:
-                arms = np.asarray(self.ctl.choose_splits(B), np.int64)
-            step = S + t
-            depths_dev = jnp.asarray(arms, jnp.int32)
-            (_, conf_all, pred_all, conf_fin, pred_fin, hidden,
-             new_caches) = self.runtime.edge_fn(
-                self.params, mgr.caches, tok, step, depths_dev, total)
-            mgr.commit_edge(new_caches, arms)
-            conf_np = np.asarray(conf_all)            # (L, B)
-            pred_np = np.asarray(pred_all)
-            conf_fin_np = np.asarray(conf_fin)
-            pred_fin_np = np.asarray(pred_fin)
-
-            # at the final arm there is no split: confidence and token come
-            # from the LM head itself, so forced-final decode IS plain
-            # full-depth generation
-            conf_paths: List[np.ndarray] = []
-            toks_next = np.empty(B, np.int32)
-            offload_rows: List[int] = []
-            conf_Ls: List[Optional[float]] = [None] * B
-            obs: List[int] = [0] * B
-            for b in range(B):
-                arm = int(arms[b])
-                ci = (float(conf_fin_np[b]) if arm + 1 == L
-                      else float(conf_np[arm, b]))
-                conf_paths.append(np.asarray([ci], np.float64))
-                if ci >= self.cost.alpha or arm + 1 == L:
-                    toks_next[b] = (pred_fin_np[b] if arm + 1 == L
-                                    else pred_np[arm, b])
-                else:
-                    offload_rows.append(b)
-
-            if offload_rows:
-                rows = np.asarray(offload_rows, np.int64)
-                hidden_np = np.asarray(hidden)
-                dec_rows, hid_wire = mgr.ship_hidden(hidden_np, rows)
-                hid_in = hidden_np.copy()
-                hid_in[rows] = dec_rows
-                active = np.zeros(B, bool)
-                active[rows] = True
-                _, conf_L_d, pred_L_d, new_caches = self.runtime.cloud_fn(
-                    self.params, mgr.caches, jnp.asarray(hid_in), step,
-                    depths_dev, jnp.asarray(active), total)
-                mgr.commit_cloud(new_caches, active)
-                conf_L_np = np.asarray(conf_L_d)
-                pred_L_np = np.asarray(pred_L_d)
-                bytes_rows = mgr.meter(rows, arms, hid_wire)
-                for j, b in enumerate(rows):
-                    conf_Ls[b] = float(conf_L_np[b])
-                    obs[b] = int(bytes_rows[j])
-                    toks_next[b] = pred_L_np[b]
-            else:
-                mgr.note_no_offload()
-
-            exited = np.asarray(self.ctl.update_batch(
-                arms, conf_paths, conf_Ls, obs,
-                offload_scale=self._scale), bool)
-            self._exits_hist[t] += np.bincount(arms[exited], minlength=L)
-            exited_steps[t] = exited
-            gen[:, t] = toks_next
-            tok = jnp.asarray(toks_next)
-
-        self._wall += time.perf_counter() - t0
         self.n += B * T
         self._pushes.append({
             "tokens": gen,
@@ -262,6 +209,91 @@ class _DecodeSession:
             "wire_bytes_per_seq": mgr.wire_bytes_per_seq,
         })
 
+    def _round(self, mgr: DecodeCacheManager, tok, step: int, total: int,
+               t: int, gen: np.ndarray, exited_steps: np.ndarray):
+        """One token round at position ``step``: select → masked edge →
+        per-row exit/offload → blocking cloud resume → fold. Writes round
+        ``t``'s tokens and exits into ``gen``/``exited_steps`` and returns
+        the next round's input token on the device."""
+        tr = self.tracer
+        B = gen.shape[0]
+        L = self.cost.num_layers
+        tr.count("splitee.decode.steps")
+        with tr.span("splitee.decode.select"):
+            if self.split_policy == "final":
+                arms = np.full(B, L - 1, np.int64)
+            else:
+                arms = np.asarray(self.ctl.choose_splits(B), np.int64)
+            depths_dev = jnp.asarray(arms, jnp.int32)
+        with tr.span("splitee.decode.edge"):
+            (_, conf_all, pred_all, conf_fin, pred_fin, hidden,
+             new_caches) = self.runtime.edge_fn(
+                self.params, mgr.caches, tok, step, depths_dev, total)
+            mgr.commit_edge(new_caches, arms)
+        with tr.span("splitee.decode.edge_wait"):
+            conf_np = np.asarray(conf_all)            # (L, B)
+            pred_np = np.asarray(pred_all)
+            conf_fin_np = np.asarray(conf_fin)
+            pred_fin_np = np.asarray(pred_fin)
+
+        # at the final arm there is no split: confidence and token come
+        # from the LM head itself, so forced-final decode IS plain
+        # full-depth generation
+        with tr.span("splitee.decode.decide"):
+            conf_paths: List[np.ndarray] = []
+            toks_next = np.empty(B, np.int32)
+            offload_rows: List[int] = []
+            for b in range(B):
+                arm = int(arms[b])
+                ci = (float(conf_fin_np[b]) if arm + 1 == L
+                      else float(conf_np[arm, b]))
+                conf_paths.append(np.asarray([ci], np.float64))
+                if ci >= self.cost.alpha or arm + 1 == L:
+                    toks_next[b] = (pred_fin_np[b] if arm + 1 == L
+                                    else pred_np[arm, b])
+                else:
+                    offload_rows.append(b)
+
+        if offload_rows:
+            tr.count("splitee.decode.cloud_launches")
+            tr.count("splitee.decode.offload_rows", len(offload_rows))
+            with tr.span("splitee.decode.codec"):
+                rows = np.asarray(offload_rows, np.int64)
+                hidden_np = np.asarray(hidden)
+                dec_rows, hid_wire = mgr.ship_hidden(hidden_np, rows)
+                hid_in = hidden_np.copy()
+                hid_in[rows] = dec_rows
+                active = np.zeros(B, bool)
+                active[rows] = True
+                hid_dev, active_dev = jnp.asarray(hid_in), jnp.asarray(active)
+            with tr.span("splitee.decode.cloud"):
+                _, conf_L_d, pred_L_d, new_caches = self.runtime.cloud_fn(
+                    self.params, mgr.caches, hid_dev, step, depths_dev,
+                    active_dev, total)
+                mgr.commit_cloud(new_caches, active)
+            with tr.span("splitee.decode.cloud_wait"):
+                conf_L_np = np.asarray(conf_L_d)
+                pred_L_np = np.asarray(pred_L_d)
+
+        with tr.span("splitee.decode.fold"):
+            conf_Ls: List[Optional[float]] = [None] * B
+            obs: List[int] = [0] * B
+            if offload_rows:
+                bytes_rows = mgr.meter(rows, arms, hid_wire)
+                for j, b in enumerate(rows):
+                    conf_Ls[b] = float(conf_L_np[b])
+                    obs[b] = int(bytes_rows[j])
+                    toks_next[b] = pred_L_np[b]
+            else:
+                mgr.note_no_offload()
+            exited = np.asarray(self.ctl.update_batch(
+                arms, conf_paths, conf_Ls, obs,
+                offload_scale=self._scale), bool)
+            self._exits_hist[t] += np.bincount(arms[exited], minlength=L)
+            exited_steps[t] = exited
+            gen[:, t] = toks_next
+            return jnp.asarray(toks_next)
+
     def drain(self):
         """The cloud resume blocks inside push — nothing is in flight."""
 
@@ -271,6 +303,9 @@ class _DecodeSession:
         tot = ctl.totals
         T = self.max_new_tokens
         seqs = sum(p["tokens"].shape[0] for p in self._pushes)
+        telemetry = self.tracer.snapshot()
+        push = telemetry["spans"].get("splitee.decode.push")
+        wall = push["total_ms"] / 1e3 if push else 0.0
 
         def cat(key):
             if not self._pushes:
@@ -299,9 +334,8 @@ class _DecodeSession:
                 "split_policy": self.split_policy,
                 "sequences": seqs,
                 "tokens_generated": seqs * T,
-                "decode_wall_s": self._wall,
-                "tokens_per_sec": (seqs * T / self._wall
-                                   if self._wall > 0 else 0.0),
+                "decode_wall_s": wall,
+                "tokens_per_sec": seqs * T / wall if wall > 0 else 0.0,
                 "exits_per_layer_per_step": self._exits_hist.copy(),
                 "tokens": cat("tokens"),
                 "realized_depths": cat("realized_depths"),
@@ -310,6 +344,7 @@ class _DecodeSession:
                 "offloads_per_sequence": cat("offloads_per_seq"),
                 "wire_bytes_per_sequence": cat("wire_bytes_per_seq"),
             },
+            "telemetry": telemetry,
         }
         return out
 

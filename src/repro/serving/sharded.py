@@ -61,6 +61,7 @@ from repro.launch.shardings import param_shardings, sanitize_spec
 from repro.serving.batched import OffloadQueue, _offload_scale
 from repro.serving.offload_codec import OffloadCodec
 from repro.serving.simulator import EdgeCloudRuntime
+from repro.serving.tracing import Tracer
 
 
 def _shard_sizes(total: int, replicas: int) -> List[int]:
@@ -290,6 +291,8 @@ class _ShardedSession:
         self.n = 0
         self.overlapped = 0
         self.batch_sizes: List[int] = []   # fill levels of pushed batches
+        # holds the Engine's scheduler spans; the session records none
+        self.tracer = Tracer()
         self._driver = _PipelineDriver(
             batch_size=batch_size, overlap=overlap,
             overlap_depth=overlap_depth,
@@ -370,6 +373,7 @@ class _ShardedSession:
                             overlap_depth=self.overlap_depth,
                             batches=self._driver.batches,
                             overlapped=self.overlapped)
+        out["telemetry"] = self.tracer.snapshot()
         if self.trace is not None:
             out["trace"] = self.trace
         return out

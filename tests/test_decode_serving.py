@@ -28,6 +28,7 @@ Plus report-shape/accounting sanity and the `ServingConfig` decode
 validation surface.
 """
 import dataclasses
+import glob
 
 import jax
 import jax.numpy as jnp
@@ -307,6 +308,112 @@ def test_decode_report_shapes_and_conservation(bandit_report):
     expect = sum(raw_h + step_slice_bytes(cfg, int(d)) for d in depths_off)
     assert rep.offload_bytes == expect
     assert dec["tokens_per_sec"] > 0 and dec["decode_wall_s"] > 0
+
+
+STEP_PARTS = ("select", "edge", "edge_wait", "decide", "codec", "cloud",
+              "cloud_wait", "fold")
+
+
+def test_decode_telemetry_counts_the_rounds(bandit_report):
+    """The session's tracer counts what the report shows: a step span per
+    token round, a cloud launch per round with any row offloaded, each
+    parent's total covering its children's, and the push spans' total
+    as the decode wall time."""
+    cfg, cost, rep = bandit_report
+    sp, cnt = rep.telemetry["spans"], rep.telemetry["counts"]
+    dec = rep.decode
+    B = rep.batch_size
+    pushes = dec["sequences"] // B
+    assert sp["splitee.decode.push"]["n"] == pushes
+    assert sp["splitee.decode.prefill"]["n"] == pushes
+    assert sp["splitee.decode.step"]["n"] == pushes * T
+    assert cnt["splitee.decode.steps"] == pushes * T
+    off = np.asarray(dec["offloaded_steps"]).reshape(pushes, B, T)
+    launches = int(off.any(axis=1).sum())
+    assert 0 < launches < pushes * T
+    assert cnt["splitee.decode.cloud_launches"] == launches
+    assert cnt["splitee.decode.offload_rows"] == int(off.sum())
+    for part in ("codec", "cloud", "cloud_wait"):
+        assert sp[f"splitee.decode.{part}"]["n"] == launches
+    for part in ("select", "edge", "edge_wait", "decide", "fold"):
+        assert sp[f"splitee.decode.{part}"]["n"] == pushes * T
+    tree = {"splitee.decode.push": ("prefill", "step"),
+            "splitee.decode.step": STEP_PARTS}
+    for parent, parts in tree.items():
+        kids = sum(sp[f"splitee.decode.{k}"]["total_ms"] for k in parts)
+        assert sp[parent]["total_ms"] >= kids - 1e-6
+        assert sp[parent]["self_ms"] == pytest.approx(
+            sp[parent]["total_ms"] - kids, abs=1e-6)
+    assert all(v["self_ms"] >= 0 for v in sp.values())
+    assert dec["decode_wall_s"] == sp["splitee.decode.push"]["total_ms"] / 1e3
+
+
+def test_decode_spans_share_the_profiler_clock(tmp_path):
+    """A push under the JAX profiler writes its step spans, with their
+    ids, into the trace; the parts of a round nest inside its step span,
+    and the rounds' XLA ops (host events on the CPU backend) run inside
+    the step spans: one clock for both."""
+    from jax.profiler import ProfileData
+
+    cfg, params, rt, cost = _bed(ARCHS[0])
+    sess = _DecodeSession(rt, params, cost, batch_size=2, max_new_tokens=T)
+    batch = _prompts(cfg, 2, seed=21)
+    sess.push(batch)                       # compile outside the trace
+    before = sess.tracer.snapshot()["spans"]
+    with jax.profiler.trace(str(tmp_path)):
+        sess.push(batch)
+    after = sess.tracer.snapshot()["spans"]
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans, ops = [], []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                iv = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                if ev.name.startswith("splitee.decode."):
+                    spans.append((ev.name, iv, dict(ev.stats)))
+                elif "hlo_op" in dict(ev.stats):
+                    ops.append(iv)
+    steps = [(iv, st) for name, iv, st in spans
+             if name == "splitee.decode.step"]
+    assert sorted(int(st["step"]) for _, st in steps) == list(range(T))
+    assert {int(st["push"]) for _, st in steps} == {1}
+    parts = [iv for name, iv, _ in spans
+             if name.rsplit(".", 1)[1] in STEP_PARTS]
+    assert len(parts) == sum(
+        after[k]["n"] - before.get(k, {"n": 0})["n"]
+        for k in (f"splitee.decode.{p}" for p in STEP_PARTS) if k in after)
+    for s, e in parts:
+        assert any(a <= s and e <= b for (a, b), _ in steps)
+    inside = [(s, e) for s, e in ops
+              if any(a <= s and e <= b for (a, b), _ in steps)]
+    assert inside
+
+
+def test_decode_programs_name_their_scopes():
+    """Each decode program's ops carry its scope and the layer sweep's or
+    head's scope in their metadata (the edge's and the cloud's layer
+    sweeps are told apart in a device trace)."""
+    cfg, params, rt, cost = _bed(ARCHS[0])
+    B, total = 2, S + T
+    prompts = jnp.asarray(np.stack(
+        [p["tokens"] for p in _prompts(cfg, B)]).astype(np.int32))
+    _, caches = rt.prefill_fn(params, prompts, total)
+    tok = jnp.zeros(B, jnp.int32)
+    depths = jnp.zeros(B, jnp.int32)
+    edge = rt.edge_fn.lower(params, caches, tok, S, depths,
+                            total).as_text(debug_info=True)
+    for scope in ("splitee.edge", "splitee.layers", "splitee.exit_heads",
+                  "splitee.final_head"):
+        assert scope in edge
+    hidden = jnp.zeros((B, 1, cfg.d_model), jnp.float32)
+    cloud = rt.cloud_fn.lower(params, caches, hidden, S, depths,
+                              jnp.ones(B, bool),
+                              total).as_text(debug_info=True)
+    for scope in ("splitee.cloud", "splitee.layers", "splitee.final_head"):
+        assert scope in cloud
+    assert "splitee.exit_heads" not in cloud
+    assert "splitee.prefill" in rt.prefill_fn.lower(
+        params, prompts, total).as_text(debug_info=True)
 
 
 def test_engine_decode_matches_one_shot_serve():

@@ -351,6 +351,44 @@ def test_engine_tick_closes_partial_batch_on_deadline(served):
     assert rep.scheduler["mean_batch_fill"] == pytest.approx(3 / 8)
 
 
+def test_engine_telemetry_times_queue_wait_on_the_scheduler_clock(served):
+    """Each request's wait from arrival to the batch that takes it is
+    added on the scheduler's clock; batch formation is a span per poll or
+    flush, and the batched session counts its batches, rows and cloud
+    launches inside its push spans."""
+    _, params, rt, cost, eval_data = served
+    clk = FakeClock()
+    eng = Engine(rt, params, cost,
+                 ServingConfig(batch_size=8, scheduler="fifo",
+                               batch_deadline_ms=25.0), clock=clk)
+    samples = _samples(eval_data, 13)
+    eng.submit(samples[:3])                          # 3 wait 30 ms
+    clk.advance(0.010)
+    eng.submit(samples[3:5])                         # 2 wait 20 ms
+    clk.advance(0.020)
+    assert eng.tick() == 5
+    eng.submit(samples[5:13])                        # a full batch: 0 ms
+    clk.advance(0.004)
+    rep = eng.close()
+    sp, cnt = rep.telemetry["spans"], rep.telemetry["counts"]
+    wait = sp["splitee.sched.queue_wait"]
+    assert wait["n"] == 13
+    assert wait["total_ms"] == pytest.approx(3 * 30.0 + 2 * 20.0)
+    # one formation per submit, tick and drain
+    assert sp["splitee.sched.form"]["n"] == 5
+    assert cnt["splitee.batched.batches"] == 2
+    assert sp["splitee.batched.push"]["n"] == 2
+    assert cnt["splitee.batched.rows"] == rep.n == 13
+    offloaded = int((~rep.exited).sum())
+    assert (cnt["splitee.batched.cloud_launches"] > 0) == (offloaded > 0)
+    assert cnt["splitee.batched.cloud_launches"] <= len(set(rep.arms))
+    parts = sum(sp[f"splitee.batched.{k}"]["total_ms"]
+                for k in ("select", "edge", "cloud", "fold"))
+    push = sp["splitee.batched.push"]
+    assert push["self_ms"] == pytest.approx(push["total_ms"] - parts,
+                                            abs=1e-6)
+
+
 def test_engine_sheds_expired_and_overflow(served):
     _, params, rt, cost, eval_data = served
     clk = FakeClock()
