@@ -3,6 +3,8 @@
 Layers are *stacked* along a leading axis and iterated with ``lax.scan``
 (O(1) HLO size in depth — mirrors the paper's "one hardware module reused
 per layer" observation and keeps 512-device dry-run compiles tractable).
+The two halves of a decode-serving step iterate a traced layer range with
+``lax.fori_loop`` instead, so the layers outside it are skipped.
 
 Per-layer exit observables are collected as scan outputs: the pooled hidden
 state after every layer (tiny: (L, B, D)), from which exit confidences are
@@ -572,41 +574,36 @@ def _mask_rows(mask, new, old):
     return jax.tree.map(sel, new, old)
 
 
-def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
-                       cur_index, depths, *, window_seq_len: int = 0,
-                       conf_backend: str = "ref"):
-    """Edge half of a decode-serving step: run layers ``0..depths[b]`` per
-    sample, freezing both the hidden carry and the cache slots of deeper
-    layers (a skipped attention layer simply leaves its ring-buffer slot
-    unwritten; the ``pos`` validity mask excludes the hole at future reads,
-    so no per-layer write indices are needed — ``cur_index`` stays global).
+def _decode_layer_range(params, cfg: ModelConfig, caches, x, cur_index,
+                        lo, hi, live, *, window, record: bool = False):
+    """Run decode layers ``lo .. hi-1`` of one step (bounds traced, so the
+    loop's trip count is dynamic) over the stacked cache tree. ``live(i)``
+    gives the (B,) rows layer ``i`` advances; the others keep their carry
+    and cache slices. Each layer's parameters and cache slice are read with
+    a dynamic index and the slice is written back in place into the carried
+    tree, so the loop neither computes nor touches the layers outside the
+    range (the caches are not donated, so XLA copies the input tree into
+    the carry once).
 
-    Returns (logits, conf (L, B), pred (L, B), hidden (B, 1, D),
-    new_caches): ``logits`` is the final LM head applied to the (masked)
-    carry — meaningful for samples with depths[b] == L-1; ``conf``/``pred``
-    are every exit head's observables as in ``decode_step(all_exits=True)``;
-    ``hidden`` is the raw carry after each sample's own split layer, the
-    payload a mid-generation offload ships to the cloud.
+    Returns (x, new_caches, buf): with ``record``, ``buf`` (L, B, 1, D)
+    holds the carry after each layer that ran (zeros from ``hi`` up).
     """
-    if token_or_embed.ndim <= 1 or token_or_embed.dtype in (
-            jnp.int32, jnp.int64):
-        x = jnp.take(params["embed"],
-                     token_or_embed.reshape(-1, 1), axis=0)
-    else:
-        x = token_or_embed.astype(jnp.dtype(cfg.dtype))
-    window = cfg.effective_window(window_seq_len)
-    live = depths[:, None, None]
-
-    if cfg.family == "hybrid":
+    hybrid = cfg.family == "hybrid"
+    key = "ssm" if cfg.family in ("ssm", "hybrid") else "attn"
+    take = functools.partial(jax.lax.dynamic_index_in_dim, keepdims=False)
+    put = jax.lax.dynamic_update_index_in_dim
+    if hybrid:
         k = cfg.hybrid_attn_every
         sp = params["shared_attn"]
 
-        def body(carry, inp):
-            xx, occ = carry
-            lp, st, i = inp
-            xx2, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                           window=window)
-
+    def body(i, carry):
+        xx, stack, occ, buf = carry
+        lp = jax.tree.map(lambda a: take(a, i, 0), params["layers"])
+        st = jax.tree.map(lambda a: take(a, i, 0), stack)
+        m = live(i)
+        xx2, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
+                                       window=window)
+        if hybrid:
             def with_attn(args):
                 xx2, occ = args
                 oi = (i + 1) // k - 1
@@ -621,48 +618,68 @@ def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
                 xx2 = xx2 + ff.mlp_forward(
                     sp["mlp"], apply_norm(xx2, sp["ln2"], cfg.norm),
                     cfg.activation)
-                # shared cache: advance only the samples whose depth reaches
-                # this layer — frozen rows keep their old slot contents
-                new_sl = _mask_rows(i <= depths, new_sl, sl)
-                occ = jax.tree.map(
-                    lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
-                        buf, ns, oi, 0), occ, new_sl)
+                # shared cache: advance only the rows this layer advances
+                new_sl = _mask_rows(m, new_sl, sl)
+                occ = jax.tree.map(lambda buf, ns: put(buf, ns, oi, 0),
+                                   occ, new_sl)
                 return xx2, occ
 
             xx2, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
                                     with_attn, lambda a: a, (xx2, occ))
-            xx = jnp.where(i <= live, xx2, xx)
-            new_st = _mask_rows(i <= depths, new_st, st)
-            pooled = pool_hidden(cfg, apply_norm(xx, lp["exit_norm"],
-                                                 cfg.norm))
-            return (xx, occ), (new_st, pooled)
+        xx = jnp.where(m[:, None, None], xx2, xx)
+        new_st = _mask_rows(m, new_st, st)
+        stack = jax.tree.map(lambda a, s: put(a, s, i, 0), stack, new_st)
+        if record:
+            buf = put(buf, xx, i, 0)
+        return xx, stack, occ, buf
 
-        idx = jnp.arange(cfg.num_layers)
-        with jax.named_scope("splitee.layers"):
-            (x, occ), (new_ssm, pooled) = jax.lax.scan(
-                body, (x, caches["attn"]),
-                (params["layers"], caches["ssm"], idx), unroll=_unroll())
-        new_caches = {"ssm": new_ssm, "attn": occ}
+    buf = (jnp.zeros((cfg.num_layers,) + x.shape, x.dtype) if record
+           else None)
+    occ = caches["attn"] if hybrid else None
+    with jax.named_scope("splitee.layers"):
+        x, stack, occ, buf = jax.lax.fori_loop(
+            lo, hi, body, (x, caches[key], occ, buf))
+    new_caches = {"ssm": stack, "attn": occ} if hybrid else {key: stack}
+    return x, new_caches, buf
+
+
+def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
+                       cur_index, depths, *, window_seq_len: int = 0,
+                       conf_backend: str = "ref"):
+    """Edge half of a decode-serving step: run layers ``0..depths[b]`` per
+    sample. The layer loop stops at ``max(depths)``: layers above it are
+    skipped, neither computed nor rewritten. Inside the loop a row whose
+    depth is below the layer keeps its hidden carry and that layer's cache
+    slot (a skipped attention layer simply leaves its ring-buffer slot
+    unwritten; the ``pos`` validity mask excludes the hole at future reads,
+    so no per-layer write indices are needed — ``cur_index`` stays global).
+
+    Returns (logits, conf (L, B), pred (L, B), hidden (B, 1, D),
+    new_caches): ``logits`` is the final LM head applied to the carry —
+    meaningful for samples with depths[b] == L-1; ``conf``/``pred`` are
+    every exit head's observables as in ``decode_step(all_exits=True)``,
+    an exit above a row's depth reading that row's carry at its depth;
+    ``hidden`` is the raw carry after each sample's own split layer, the
+    payload a mid-generation offload ships to the cloud.
+    """
+    if token_or_embed.ndim <= 1 or token_or_embed.dtype in (
+            jnp.int32, jnp.int64):
+        x = jnp.take(params["embed"],
+                     token_or_embed.reshape(-1, 1), axis=0)
     else:
-        cache_key = "ssm" if cfg.family == "ssm" else "attn"
+        x = token_or_embed.astype(jnp.dtype(cfg.dtype))
+    L = cfg.num_layers
+    hi = jnp.minimum(jnp.max(depths) + 1, L)
+    x, new_caches, buf = _decode_layer_range(
+        params, cfg, caches, x, cur_index, 0, hi, lambda i: i <= depths,
+        window=cfg.effective_window(window_seq_len), record=True)
 
-        def body(xx, inp):
-            lp, st, i = inp
-            xx2, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                           window=window)
-            xx = jnp.where(i <= live, xx2, xx)
-            new_st = _mask_rows(i <= depths, new_st, st)
-            pooled = pool_hidden(cfg, apply_norm(xx, lp["exit_norm"],
-                                                 cfg.norm))
-            return xx, (new_st, pooled)
-
-        idx = jnp.arange(cfg.num_layers)
-        with jax.named_scope("splitee.layers"):
-            x, (new_st, pooled) = jax.lax.scan(
-                body, x, (params["layers"], caches[cache_key], idx),
-                unroll=_unroll())
-        new_caches = {cache_key: new_st}
-
+    # every exit's hidden: the carry after layer i up to a row's depth,
+    # its final carry above it (where the masked rows stood still)
+    ran = jnp.arange(L)[:, None] <= depths[None, :]
+    hs = jnp.where(ran[:, :, None, None], buf, x[None])
+    pooled = jax.vmap(lambda h, n: pool_hidden(cfg, apply_norm(
+        h, n, cfg.norm)))(hs, params["layers"]["exit_norm"])
     with jax.named_scope("splitee.exit_heads"):
         conf, pred = stacked_exit_confidence(params, cfg, pooled,
                                              conf_backend=conf_backend)
@@ -679,77 +696,22 @@ def decode_step_resume(params, cfg: ModelConfig, caches, hidden,
                        window_seq_len: int = 0):
     """Cloud half of a decode-serving step: resume from the shipped edge
     carry ``hidden`` (B, 1, D) and run layers ``depths[b]+1 .. L-1`` for the
-    samples with ``active[b]`` set; everything else (inactive samples, and
-    layers the edge already advanced) passes through untouched — the
-    returned cache tree is bitwise the input tree at those coordinates, so
-    merging it back re-syncs the edge cache.
+    samples with ``active[b]`` set. The layer loop starts above the
+    shallowest active depth (and runs nothing when no row is active):
+    layers below it are skipped, neither computed nor rewritten. Inside
+    the loop, inactive rows and layers the edge already advanced pass
+    through untouched — the returned cache tree is bitwise the input tree
+    at those coordinates, so merging it back re-syncs the edge cache.
 
     Returns (logits, new_caches).
     """
     x = hidden.astype(jnp.dtype(cfg.dtype))
-    window = cfg.effective_window(window_seq_len)
-
-    if cfg.family == "hybrid":
-        k = cfg.hybrid_attn_every
-        sp = params["shared_attn"]
-
-        def body(carry, inp):
-            xx, occ = carry
-            lp, st, i = inp
-            m = active & (i > depths)
-            xx2, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                           window=window)
-
-            def with_attn(args):
-                xx2, occ = args
-                oi = (i + 1) // k - 1
-                sl = jax.tree.map(lambda a: a[oi], occ)
-                h, new_sl = attn.attn_decode(
-                    sp["attn"], apply_norm(xx2, sp["ln1"], cfg.norm), sl,
-                    cur_index, num_heads=cfg.num_heads,
-                    num_kv_heads=cfg.num_kv_heads,
-                    head_dim=cfg.resolved_head_dim, window=window,
-                    rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
-                xx2 = xx2 + h
-                xx2 = xx2 + ff.mlp_forward(
-                    sp["mlp"], apply_norm(xx2, sp["ln2"], cfg.norm),
-                    cfg.activation)
-                new_sl = _mask_rows(m, new_sl, sl)
-                occ = jax.tree.map(
-                    lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
-                        buf, ns, oi, 0), occ, new_sl)
-                return xx2, occ
-
-            xx2, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
-                                    with_attn, lambda a: a, (xx2, occ))
-            xx = jnp.where(m[:, None, None], xx2, xx)
-            new_st = _mask_rows(m, new_st, st)
-            return (xx, occ), new_st
-
-        idx = jnp.arange(cfg.num_layers)
-        with jax.named_scope("splitee.layers"):
-            (x, occ), new_ssm = jax.lax.scan(
-                body, (x, caches["attn"]),
-                (params["layers"], caches["ssm"], idx), unroll=_unroll())
-        new_caches = {"ssm": new_ssm, "attn": occ}
-    else:
-        cache_key = "ssm" if cfg.family == "ssm" else "attn"
-
-        def body(xx, inp):
-            lp, st, i = inp
-            m = active & (i > depths)
-            xx2, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                           window=window)
-            xx = jnp.where(m[:, None, None], xx2, xx)
-            new_st = _mask_rows(m, new_st, st)
-            return xx, new_st
-
-        idx = jnp.arange(cfg.num_layers)
-        with jax.named_scope("splitee.layers"):
-            x, new_st = jax.lax.scan(
-                body, x, (params["layers"], caches[cache_key], idx),
-                unroll=_unroll())
-        new_caches = {cache_key: new_st}
+    L = cfg.num_layers
+    lo = jnp.min(jnp.where(active, depths, L - 1)) + 1
+    x, new_caches, _ = _decode_layer_range(
+        params, cfg, caches, x, cur_index, lo, L,
+        lambda i: active & (i > depths),
+        window=cfg.effective_window(window_seq_len))
 
     with jax.named_scope("splitee.final_head"):
         ew = params["exit_w"] if "exit_w" in params \

@@ -3,9 +3,11 @@
 The classifier runtimes decide once per *sample*; here the bandit decides
 once per *token*: every decode step draws a splitting layer from the UCB
 state (eq. 1 unchanged — confidence is the exit head's max-softmax on the
-step's hidden), the edge runs layers ``0..ℓ`` with per-layer cache slots
-frozen above each sample's depth (``transformer.decode_step_masked``), and
-a token either
+step's hidden), the edge runs layers ``0..ℓ``
+(``transformer.decode_step_masked``: its layer loop stops at the step's
+deepest split, so layers above it are skipped, not masked; a shallower
+sample's carry and cache slots stand still inside the loop), and a token
+either
 
 * **exits** at ℓ — the exit head's argmax becomes the generated token and
   layers > ℓ never advance their cache for this step (the attention ring
@@ -15,9 +17,10 @@ a token either
   :class:`OffloadCodec` round trip (the cloud computes on the
   reconstruction, so quantization loss is visible end to end) together
   with the per-step ≤ℓ cache-slice bytes; ``decode_step_resume`` completes
-  layers > ℓ for exactly the offloaded samples and its returned tree —
-  bitwise the input everywhere it did not advance — re-syncs the edge
-  cache on commit.
+  layers > ℓ for exactly the offloaded samples (its loop starts above the
+  shallowest offloaded split: the layers below are skipped, not masked)
+  and its returned tree — bitwise the input everywhere it did not
+  advance — re-syncs the edge cache on commit.
 
 The cloud call blocks: unlike the classifier's deferred flush queue, step
 t+1 cannot start until t's token exists — the serial dependency is
@@ -225,6 +228,9 @@ class _DecodeSession:
             else:
                 arms = np.asarray(self.ctl.choose_splits(B), np.int64)
             depths_dev = jnp.asarray(arms, jnp.int32)
+        # layers each program's loop runs: the edge's stops at the deepest
+        # split, the cloud's starts above the shallowest offloaded one
+        tr.count("splitee.decode.edge_layers", int(arms.max()) + 1)
         with tr.span("splitee.decode.edge"):
             (_, conf_all, pred_all, conf_fin, pred_fin, hidden,
              new_caches) = self.runtime.edge_fn(
@@ -257,6 +263,8 @@ class _DecodeSession:
         if offload_rows:
             tr.count("splitee.decode.cloud_launches")
             tr.count("splitee.decode.offload_rows", len(offload_rows))
+            tr.count("splitee.decode.cloud_layers",
+                     L - 1 - int(arms[offload_rows].min()))
             with tr.span("splitee.decode.codec"):
                 rows = np.asarray(offload_rows, np.int64)
                 hidden_np = np.asarray(hidden)

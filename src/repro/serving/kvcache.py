@@ -11,7 +11,9 @@ classifier stream never had:
   the hole at every future read, so ``cur_index`` stays the *global* step
   for all layers and RoPE positions stay global. Recurrent states (rwkv6 /
   mamba2) are frozen with a per-sample ``jnp.where`` select. Both are
-  implemented inside ``transformer.decode_step_masked``; this manager owns
+  implemented inside ``transformer.decode_step_masked``, whose layer loop
+  stops at the step's deepest split: layers above it are skipped, not
+  masked, and the per-sample freeze acts only below it. This manager owns
   the resulting cache tree and the realized-depth ledger.
 
 * **Mid-generation offload** — the edge ships the split-layer hidden
@@ -21,7 +23,8 @@ classifier stream never had:
   per-step ≤ℓ cache-slice update at raw bytes (the cloud needs layers ≤ ℓ
   current to keep decoding; the slice is structured state, shipped
   unquantized). The cloud half (``decode_step_resume``) advances only
-  layers > ℓ of offloaded samples and passes everything else through
+  layers > ℓ of offloaded samples — its loop skips the layers at or below
+  the shallowest offloaded split — and passes everything else through
   bitwise, so merging its returned tree back IS the edge re-sync.
 
 Wire accounting is exact and closed-form: ``step_slice_bytes`` prices the

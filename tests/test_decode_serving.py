@@ -348,6 +348,27 @@ def test_decode_telemetry_counts_the_rounds(bandit_report):
     assert dec["decode_wall_s"] == sp["splitee.decode.push"]["total_ms"] / 1e3
 
 
+def test_decode_telemetry_counts_the_layers_run(bandit_report):
+    """The layer counters add up each round's loop bounds from the
+    realized depths: the edge runs up to the round's deepest split, the
+    cloud from above the shallowest offloaded split to the last layer."""
+    cfg, cost, rep = bandit_report
+    cnt, dec = rep.telemetry["counts"], rep.decode
+    B, L = rep.batch_size, cost.num_layers
+    depths = np.asarray(dec["realized_depths"]).reshape(-1, B, T)
+    off = np.asarray(dec["offloaded_steps"]).reshape(-1, B, T)
+    edge = cloud = 0
+    for d, o in zip(depths, off):                  # one push: (B, T)
+        for t in range(T):
+            edge += int(d[:, t].max()) + 1
+            if o[:, t].any():
+                cloud += L - 1 - int(d[o[:, t], t].min())
+    assert cnt["splitee.decode.edge_layers"] == edge
+    assert cnt["splitee.decode.cloud_layers"] == cloud
+    steps = cnt["splitee.decode.steps"]
+    assert steps <= edge <= L * steps
+
+
 def test_decode_spans_share_the_profiler_clock(tmp_path):
     """A push under the JAX profiler writes its step spans, with their
     ids, into the trace; the parts of a round nest inside its step span,
