@@ -33,9 +33,13 @@ _REGISTRY: Dict[str, str] = {
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     # the paper's own testbed geometry (not part of the assigned 10)
     "elasticbert12": "elasticbert12",
+    # a benchmark configuration (not part of the assigned 10)
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
-ASSIGNED_ARCHS: List[str] = [a for a in _REGISTRY if a != "elasticbert12"]
+ASSIGNED_ARCHS: List[str] = [a for a in _REGISTRY
+                             if a not in ("elasticbert12",
+                                          "granite-4.0-h-micro")]
 
 
 def get_config(arch_id: str) -> ModelConfig:

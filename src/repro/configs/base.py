@@ -71,6 +71,8 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    position_embedding: str = "rope"   # rope | nope (rope_theta unused)
+    attention_multiplier: float = 0.0  # score scale; 0 -> 1/sqrt(head_dim)
     mrope: bool = False            # multimodal rotary (qwen2-vl)
     sliding_window: int = 0        # 0 -> full causal attention (native)
     # beyond-paper: force a window for long_500k on full-attention archs
@@ -81,6 +83,10 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     # hybrid (zamba2): 1 shared attention block interleaved every k mamba blocks
     hybrid_attn_every: int = 0     # 0 -> not hybrid
+    # hybrid (granite-4.0-h): the mixer of each layer, "mamba" or
+    # "attention", each layer with its own mixer weights and its own MLP;
+    # () -> every layer is the family's block
+    layer_types: Tuple[str, ...] = ()
     encoder: Optional[EncoderConfig] = None
 
     # frontends (stubbed per assignment: input_specs() feeds embeddings)
@@ -88,6 +94,12 @@ class ModelConfig:
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     activation: str = "swiglu"     # swiglu | gelu_mlp
     tie_embeddings: bool = False
+    norm_eps: float = 1e-6
+    # Granite's scalings: the embedding is multiplied, each block's branch
+    # multiplied before its residual add, the logits divided
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     exits: ExitConfig = ExitConfig()
     dtype: str = "bfloat16"
@@ -98,6 +110,21 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def attn_rope_theta(self) -> float:
+        """The rotary base attention applies; 0 (none) under NoPE."""
+        return self.rope_theta if self.position_embedding == "rope" else 0.0
+
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The per-layer mixer types, checked against the depth."""
+        if len(self.layer_types) != self.num_layers or not set(
+                self.layer_types) <= {"mamba", "attention"}:
+            raise ValueError(
+                f"{self.arch_id}: layer_types must name 'mamba' or "
+                f"'attention' for each of the {self.num_layers} layers, got "
+                f"{self.layer_types!r}")
+        return self.layer_types
 
     @property
     def exit_layers(self) -> Tuple[int, ...]:
@@ -134,7 +161,18 @@ class ModelConfig:
             mlp = 2 * d * f
         per_layer = 0
         n_attn = n_mix = self.num_layers
-        if self.family == "ssm" and self.ssm is not None:
+        if self.layer_types:
+            # per-layer mixers (mamba2 or attention), each with its own MLP
+            from repro.models.mamba2 import HEAD_DIM
+            d_in = self.ssm.expand * d
+            conv_dim = d_in + 2 * self.ssm.state_size
+            heads = d_in // HEAD_DIM
+            mamba = (d * (d_in + conv_dim + heads) + 4 * conv_dim
+                     + conv_dim + 3 * heads + d_in + d_in * d)
+            n_attn = sum(t == "attention" for t in self.layer_types)
+            total_layers = (n_attn * attn + (self.num_layers - n_attn) * mamba
+                            + self.num_layers * (mlp + 2 * d))
+        elif self.family == "ssm" and self.ssm is not None:
             # rwkv6: time-mix (~4.5 d^2 with lora decays) + channel-mix 2*d*f
             per_layer = int(5 * d * d) + 2 * d * f
             total_layers = per_layer * self.num_layers
@@ -200,7 +238,8 @@ INPUT_SHAPES = {
 
 
 def smoke_variant(cfg: ModelConfig) -> ModelConfig:
-    """Reduced config of the same family: 2 layers, d_model<=512, <=4 experts."""
+    """Reduced config of the same family: 2 layers (one of each type where
+    layers are typed), d_model<=512, <=4 experts."""
     d = min(cfg.d_model, 128)
     heads = min(cfg.num_heads, 4)
     kv = max(1, min(cfg.num_kv_heads, heads))
@@ -219,9 +258,12 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         enc = dataclasses.replace(
             cfg.encoder, num_layers=2, d_model=d, num_heads=heads,
             num_kv_heads=kv, d_ff=4 * d, source_len=32)
+    # one layer of each mixer type, in the order they first appear
+    kinds = tuple(dict.fromkeys(cfg.layer_types))
     return dataclasses.replace(
         cfg,
-        num_layers=2,
+        num_layers=len(kinds) or 2,
+        layer_types=kinds,
         d_model=d,
         num_heads=heads,
         num_kv_heads=kv,

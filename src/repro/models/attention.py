@@ -70,8 +70,9 @@ def attn_prefill(p, x, positions, *, num_heads, num_kv_heads, head_dim,
                  causal: bool = True, window: int = 0,
                  rope_theta: float = 10000.0, qk_norm: bool = False,
                  mrope: bool = False, backend: str = "ref",
-                 x_kv=None, return_kv: bool = False):
-    """Full-sequence attention. x_kv set -> cross-attention (non-causal)."""
+                 x_kv=None, return_kv: bool = False, scale=None):
+    """Full-sequence attention. x_kv set -> cross-attention (non-causal).
+    ``scale`` multiplies the scores (None: 1/sqrt(head_dim))."""
     b, s, d = x.shape
     q, k, v = _project_qkv(p, x, num_heads, num_kv_heads, head_dim,
                            qk_norm=qk_norm, rope_theta=rope_theta,
@@ -79,7 +80,7 @@ def attn_prefill(p, x, positions, *, num_heads, num_kv_heads, head_dim,
     out = flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), causal=causal, window=window,
-        backend=backend)
+        backend=backend, scale=scale)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, num_heads * head_dim)
     out = out @ p["wo"]
     if return_kv:
@@ -116,9 +117,10 @@ def fill_cache(cache, k, v, start: int = 0):
 
 def attn_decode(p, x, cache, cur_index, *, num_heads, num_kv_heads, head_dim,
                 window: int = 0, rope_theta: float = 10000.0,
-                qk_norm: bool = False, mrope: bool = False):
+                qk_norm: bool = False, mrope: bool = False, scale=None):
     """One-token decode. x: (B, 1, D); cur_index: scalar i32 (position of
-    the new token). Returns (out (B,1,D), new_cache)."""
+    the new token); ``scale`` multiplies the scores (None:
+    1/sqrt(head_dim)). Returns (out (B,1,D), new_cache)."""
     b = x.shape[0]
     w = cache["k"].shape[1]
     if mrope:
@@ -143,7 +145,8 @@ def attn_decode(p, x, cache, cur_index, *, num_heads, num_kv_heads, head_dim,
     qg = q.reshape(b, num_kv_heads, g, head_dim).astype(jnp.float32)
     kf = k_cache.astype(jnp.float32)                  # (B, W, Hkv, hd)
     vf = v_cache.astype(jnp.float32)
-    scores = jnp.einsum("bngd,bwnd->bngw", qg, kf) * (head_dim ** -0.5)
+    scores = jnp.einsum("bngd,bwnd->bngw", qg, kf) * (
+        head_dim ** -0.5 if scale is None else scale)
     pos = pos_cache                                   # (B, W)
     valid = (pos >= 0) & (pos <= cur_index)
     if window:

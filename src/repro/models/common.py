@@ -42,10 +42,10 @@ def init_norm(key, d: int, kind: str, dtype):
     return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
 
 
-def apply_norm(x, p, kind: str):
+def apply_norm(x, p, kind: str, eps: float = 1e-6):
     if kind == "rmsnorm":
-        return rmsnorm(x, p["scale"])
-    return layernorm(x, p["scale"], p["bias"])
+        return rmsnorm(x, p["scale"], eps)
+    return layernorm(x, p["scale"], p["bias"], eps)
 
 
 # ---------------------------------------------------------------------- RoPE

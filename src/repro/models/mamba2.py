@@ -97,8 +97,9 @@ def _ssd_chunked(xh, bmat, cmat, dt, a, h0, chunk: int):
 
 
 def mamba2_forward(p, x, state, *, state_size: int, expand: int,
-                   chunk: int = 128):
-    """x: (B, S, D); state: {"conv": (B,K-1,C), "ssm": (B,H,P,N)}."""
+                   chunk: int = 128, norm_eps: float = 1e-6):
+    """x: (B, S, D); state: {"conv": (B,K-1,C), "ssm": (B,H,P,N)}.
+    ``norm_eps`` is the gated RMSNorm's epsilon."""
     b, s, d = x.shape
     d_inner = expand * d
     nheads = d_inner // HEAD_DIM
@@ -137,7 +138,7 @@ def mamba2_forward(p, x, state, *, state_size: int, expand: int,
     # gated RMSNorm (Mamba2 style)
     y = y * jax.nn.silu(z)
     var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)
+    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + norm_eps)
          * p["norm_scale"].astype(jnp.float32)).astype(x.dtype)
     out = y @ p["w_out"]
     return out, {"conv": new_conv.astype(jnp.float32), "ssm": hnew}

@@ -13,7 +13,18 @@ so SplitEE (single exit check) and SplitEE-S (all exits) share one forward.
 
 Families: dense (llama/qwen/granite), moe (mixtral/phi), ssm (rwkv6),
 hybrid (zamba2: mamba2 backbone + one shared attention block every k
-layers). Enc-dec (seamless) wraps this module — see encdec.py.
+layers; granite-4.0-h: ``layer_types`` names each layer's mixer, Mamba2 or
+attention, each layer with its own mixer and MLP). Enc-dec (seamless)
+wraps this module — see encdec.py.
+
+Typed layers keep their mixers' weights and decode state once per type:
+``params["mixers"]`` holds a stacked "mamba" and a stacked "attention"
+tree, and the cache tree a stacked "ssm" and a stacked "attn" entry, each
+as deep as its type has layers. Every layer loop runs the layers as runs
+of one type (`_runs`), each run a loop of its own. A typed layer's mixer
+reaches the layer functions under its type's key in the layer's
+parameters ("mamba" or "attn"), which is how they pick it: statically, so
+the program holds no branch over the types.
 """
 from __future__ import annotations
 
@@ -48,10 +59,106 @@ def _unroll() -> int:
 
 # ------------------------------------------------------------------- helpers
 
-def _is_attn_layer(cfg: ModelConfig, i: int) -> bool:
-    """Hybrid: shared attention block applied after layers k, 2k, ... ."""
-    k = cfg.hybrid_attn_every
-    return bool(k) and (i + 1) % k == 0
+def _norm(cfg: ModelConfig, x, p):
+    return apply_norm(x, p, cfg.norm, cfg.norm_eps)
+
+
+def _head_in(cfg: ModelConfig, h):
+    """A head's input: the normed hidden, divided by ``logits_scaling``
+    where the model divides its logits (the same logits, and the exit
+    confidence kernels keep their (h, W) interface)."""
+    return h if cfg.logits_scaling == 1.0 else h / cfg.logits_scaling
+
+
+def _head_norm(cfg: ModelConfig, x, p):
+    return _head_in(cfg, _norm(cfg, x, p))
+
+
+def _embed_scale(cfg: ModelConfig, x):
+    m = cfg.embedding_multiplier
+    return x if m == 1.0 else x * m
+
+
+def _take(tree, i):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+        tree)
+
+
+# a typed layer's mixer: its key in the layer's parameters, which the
+# layer functions read the type from, and its state's key in the caches
+_MIXER_KEY = {"mamba": "mamba", "attention": "attn"}
+_STATE_KEY = {"mamba": "ssm", "attention": "attn"}
+
+
+def _runs(cfg: ModelConfig):
+    """The layer loops' runs, [(kind, start, stop, slot)]: one of every
+    layer (kind None) where layers are untyped, else one per stretch of
+    same-typed layers, ``slot`` its first layer's index in its type's
+    stacks."""
+    if not cfg.layer_types:
+        return [(None, 0, cfg.num_layers, 0)]
+    runs, seen = [], {}
+    for i, kind in enumerate(cfg.layer_kinds()):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] = i + 1
+        else:
+            runs.append([kind, i, i + 1, seen.get(kind, 0)])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(r) for r in runs]
+
+
+def _state_key(cfg: ModelConfig, kind) -> str:
+    if kind is not None:
+        return _STATE_KEY[kind]
+    return "ssm" if cfg.family in ("ssm", "hybrid") else "attn"
+
+
+def _layer_params(params, kind, i, j):
+    """Layer ``i``'s parameters, read by index; a typed layer's mixer (slot
+    ``j`` of its type's stack) rides along under its type's key."""
+    lp = _take(params["layers"], i)
+    if kind is not None:
+        lp[_MIXER_KEY[kind]] = _take(params["mixers"][kind], j)
+    return lp
+
+
+def _scan_layers(cfg: ModelConfig, params, body, carry, states=None):
+    """``lax.scan`` of ``body(carry, (lp, st, i)) -> (carry, (new_st, y))``
+    over every layer: ``lp`` is layer i's parameters and ``st`` its entry
+    of its state stack in ``states`` (None without). Returns (carry, the
+    ``new_st`` as a state tree or None, the ``y`` stacked over the layers).
+
+    Untyped layers are scanned as their stacks. Typed ones are scanned run
+    by run (`_runs`) over their indices, reading ``lp`` and ``st`` by index
+    from the whole stacks (a slice of a stack would be a copy of it); each
+    type's new states are joined over its runs."""
+    news, ys = {}, []
+    for kind, a, b, s in _runs(cfg):
+        key = _state_key(cfg, kind)
+        if kind is None:
+            st = None if states is None else states[key]
+            carry, (new, y) = jax.lax.scan(
+                body, carry, (params["layers"], st, jnp.arange(b)),
+                unroll=_unroll())
+        else:
+            def run(c, i, kind=kind, key=key, off=s - a):
+                st = None if states is None else _take(states[key], i + off)
+                return body(c, (_layer_params(params, kind, i, i + off), st,
+                                i))
+
+            carry, (new, y) = jax.lax.scan(run, carry, jnp.arange(a, b),
+                                           unroll=_unroll())
+        if new is not None:
+            news.setdefault(key, []).append(new)
+        ys.append(y)
+
+    def join(parts):
+        if len(parts) == 1:
+            return parts[0]
+        return jax.tree.map(lambda *p: jnp.concatenate(p), *parts)
+
+    return carry, ({k: join(v) for k, v in news.items()} or None), join(ys)
 
 
 def head_out_dim(cfg: ModelConfig) -> int:
@@ -70,7 +177,12 @@ def _init_layer(cfg: ModelConfig, key) -> PyTree:
     ks = jax.random.split(key, 8)
     dt = jnp.dtype(cfg.dtype)
     p: Dict[str, Any] = {}
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        # the mixer's weights live in its type's stack (init_params)
+        p["ln1"] = init_norm(ks[0], d, cfg.norm, dt)
+        p["ln2"] = init_norm(ks[2], d, cfg.norm, dt)
+        p["mlp"] = ff.init_mlp(ks[3], d, cfg.d_ff, cfg.activation, dt)
+    elif cfg.family == "ssm":
         heads = cfg.ssm.num_heads or d // cfg.ssm.state_size
         p["ln1"] = init_norm(ks[0], d, cfg.norm, dt)
         p["tm"] = rk.init_rwkv6(ks[1], d, heads, cfg.d_ff, dt)
@@ -113,7 +225,21 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
     if cfg.exits.share_head or not cfg.exits.enabled:
         params["exit_w"] = dense_init(ks[3], cfg.d_model,
                                       head_out_dim(cfg), dt)
-    if cfg.family == "hybrid":
+    if cfg.layer_types:
+        kinds = cfg.layer_kinds()
+        n_attn = kinds.count("attention")
+        km, ka = jax.random.split(ks[5])
+        params["mixers"] = {
+            "mamba": jax.vmap(lambda k: m2.init_mamba2(
+                k, cfg.d_model, cfg.ssm.state_size, cfg.ssm.expand, dt))(
+                jax.random.split(km, len(kinds) - n_attn)),
+            "attention": jax.vmap(lambda k: attn.init_attention(
+                k, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+                cfg.resolved_head_dim, qkv_bias=cfg.qkv_bias,
+                qk_norm=cfg.qk_norm, dtype=dt))(
+                jax.random.split(ka, n_attn)),
+        }
+    elif cfg.family == "hybrid":
         hd = cfg.resolved_head_dim
         kk = jax.random.split(ks[4], 4)
         params["shared_attn"] = {
@@ -141,7 +267,7 @@ def embed_inputs(params, cfg: ModelConfig, batch: Dict[str, Any]):
         x = batch["embeds"].astype(jnp.dtype(cfg.dtype))
     else:
         x = jnp.take(params["embed"], batch["tokens"], axis=0)
-    return constrain(x, "batch", None, None)
+    return constrain(_embed_scale(cfg, x), "batch", None, None)
 
 
 def _positions(cfg: ModelConfig, b: int, s: int, offset=0):
@@ -152,21 +278,90 @@ def _positions(cfg: ModelConfig, b: int, s: int, offset=0):
     return pos
 
 
+# -------------------------------------------------------------- typed layers
+
+def _attn_kw(cfg: ModelConfig):
+    return dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.resolved_head_dim,
+                rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm,
+                mrope=cfg.mrope, scale=cfg.attention_multiplier or None)
+
+
+def _mamba_kw(cfg: ModelConfig):
+    return dict(state_size=cfg.ssm.state_size, expand=cfg.ssm.expand,
+                chunk=cfg.ssm.chunk_size, norm_eps=cfg.norm_eps)
+
+
+def _typed_block(cfg: ModelConfig, lp, x, mixer):
+    """One typed layer around its mixer: ``h, state = mixer(rms(x))``, then
+    ``x + r h`` and ``x + r mlp(rms(x))`` (r: ``residual_multiplier``).
+    Returns (x, state)."""
+    r = cfg.residual_multiplier
+    h, state = mixer(_norm(cfg, x, lp["ln1"]))
+    x = x + r * h
+    with jax.named_scope("splitee.mlp"):
+        h = ff.mlp_forward(lp["mlp"], _norm(cfg, x, lp["ln2"]),
+                           cfg.activation)
+    return x + r * h, state
+
+
+def _typed_mixer_seq(cfg: ModelConfig, lp, xn, positions, *, window: int,
+                     backend: str, cache_window: int = 0):
+    """A typed layer's mixer over a whole sequence ``xn`` (B, S, D): Mamba2
+    (``lp["mamba"]``) from a zero state, or attention (``lp["attn"]``).
+    Returns (h, state): Mamba2's final SSM and conv state, or, with a
+    ``cache_window`` (prefill), the attention cache holding the last keys
+    and values at their ring slots (else None)."""
+    b, s, _ = xn.shape
+    if "mamba" in lp:
+        with jax.named_scope("splitee.mamba"):
+            st = m2.init_mamba2_state(b, cfg.d_model, cfg.ssm.state_size,
+                                      cfg.ssm.expand)
+            return m2.mamba2_forward(lp["mamba"], xn, st, **_mamba_kw(cfg))
+    with jax.named_scope("splitee.attn"):
+        h, (k, v) = attn.attn_prefill(
+            lp["attn"], xn, positions, causal=cfg.causal, window=window,
+            backend=backend, return_kv=True, **_attn_kw(cfg))
+        if not cache_window:
+            return h, None
+        w = cache_window
+        c = attn.init_cache(b, w, cfg.num_kv_heads, cfg.resolved_head_dim,
+                            jnp.dtype(cfg.dtype))
+        return h, attn.fill_cache(c, k[:, -w:], v[:, -w:],
+                                  start=max(0, s - w))
+
+
+def _typed_mixer_step(cfg: ModelConfig, lp, xn, st, cur_index, *,
+                      window: int):
+    """A typed layer's mixer for one token ``xn`` (B, 1, D) from its state
+    ``st``: Mamba2 (``lp["mamba"]``) or attention (``lp["attn"]``).
+    Returns (h, new state)."""
+    if "mamba" in lp:
+        with jax.named_scope("splitee.mamba"):
+            return m2.mamba2_forward(lp["mamba"], xn, st, **_mamba_kw(cfg))
+    with jax.named_scope("splitee.attn"):
+        return attn.attn_decode(lp["attn"], xn, st, cur_index,
+                                window=window, **_attn_kw(cfg))
+
+
 # ------------------------------------------------------------ full-seq layer
 
 def _layer_full(cfg: ModelConfig, params, lp, x, positions, i, *,
                 window: int, backend: str):
     """One layer over the full sequence. Returns (x, aux)."""
     aux = jnp.zeros((), jnp.float32)
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        x, _ = _typed_block(cfg, lp, x, lambda xn: _typed_mixer_seq(
+            cfg, lp, xn, positions, window=window, backend=backend))
+    elif cfg.family == "ssm":
         b = x.shape[0]
         heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
         st = rk.init_rwkv_state(b, cfg.d_model, heads)
-        h, _ = rk.time_mix(lp["tm"], apply_norm(x, lp["ln1"], cfg.norm),
+        h, _ = rk.time_mix(lp["tm"], _norm(cfg, x, lp["ln1"]),
                            (st["tm_last"], st["wkv"]), num_heads=heads,
                            backend=backend, chunk=cfg.ssm.chunk_size)
         x = x + h
-        h, _ = rk.channel_mix(lp["cm"], apply_norm(x, lp["ln2"], cfg.norm),
+        h, _ = rk.channel_mix(lp["cm"], _norm(cfg, x, lp["ln2"]),
                               st["cm_last"])
         x = x + h
     elif cfg.family == "hybrid":
@@ -174,7 +369,7 @@ def _layer_full(cfg: ModelConfig, params, lp, x, positions, i, *,
         st = m2.init_mamba2_state(b, cfg.d_model, cfg.ssm.state_size,
                                   cfg.ssm.expand)
         h, _ = m2.mamba2_forward(
-            lp["mamba"], apply_norm(x, lp["ln1"], cfg.norm), st,
+            lp["mamba"], _norm(cfg, x, lp["ln1"]), st,
             state_size=cfg.ssm.state_size, expand=cfg.ssm.expand,
             chunk=cfg.ssm.chunk_size)
         x = x + h
@@ -182,14 +377,14 @@ def _layer_full(cfg: ModelConfig, params, lp, x, positions, i, *,
         def shared_block(xx):
             sp = params["shared_attn"]
             h2 = attn.attn_prefill(
-                sp["attn"], apply_norm(xx, sp["ln1"], cfg.norm), positions,
+                sp["attn"], _norm(cfg, xx, sp["ln1"]), positions,
                 num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-                window=window, rope_theta=cfg.rope_theta,
+                window=window, rope_theta=cfg.attn_rope_theta,
                 qk_norm=cfg.qk_norm, backend=backend)
             xx = xx + h2
             h2 = ff.mlp_forward(sp["mlp"],
-                                apply_norm(xx, sp["ln2"], cfg.norm),
+                                _norm(cfg, xx, sp["ln2"]),
                                 cfg.activation)
             return xx + h2
 
@@ -198,13 +393,13 @@ def _layer_full(cfg: ModelConfig, params, lp, x, positions, i, *,
                          shared_block, lambda xx: xx, x)
     else:
         h = attn.attn_prefill(
-            lp["attn"], apply_norm(x, lp["ln1"], cfg.norm), positions,
+            lp["attn"], _norm(cfg, x, lp["ln1"]), positions,
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-            window=window, rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+            window=window, rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm,
             mrope=cfg.mrope, backend=backend)
         x = x + h
-        x2 = apply_norm(x, lp["ln2"], cfg.norm)
+        x2 = _norm(cfg, x, lp["ln2"])
         if cfg.family == "moe":
             h, aux = ff.moe_forward(lp["moe"], x2,
                                     num_experts=cfg.moe.num_experts,
@@ -243,7 +438,7 @@ def train_loss(params, cfg: ModelConfig, batch: Dict[str, Any], *,
         else ("batch", None, None)
 
     def exit_ce(params_exit_w, lp, xx):
-        hn = apply_norm(xx, lp["exit_norm"], cfg.norm)
+        hn = _head_norm(cfg, xx, lp["exit_norm"])
         w = _exit_w({"exit_w": params_exit_w}, lp)
         if cfg.num_classes:
             logits = pool_hidden(cfg, hn) @ w            # (B, C)
@@ -254,21 +449,19 @@ def train_loss(params, cfg: ModelConfig, batch: Dict[str, Any], *,
 
     def body(carry, inp):
         xx, aux = carry
-        lp, i = inp
+        lp, _, i = inp
         xx, a = _layer_full(cfg, params, lp, xx, positions, i,
                             window=window, backend=backend)
         loss_i = exit_ce(params.get("exit_w"), lp, xx) \
             if cfg.exits.enabled else jnp.zeros((), jnp.float32)
         xx = constrain(xx, *carry_spec)
-        return (xx, aux + a), loss_i
+        return (xx, aux + a), (None, loss_i)
 
     body_fn = jax.checkpoint(body) if remat else body
-    idx = jnp.arange(cfg.num_layers)
-    (x, aux), exit_losses = jax.lax.scan(
-        body_fn, (x, jnp.zeros((), jnp.float32)), (params["layers"], idx),
-        unroll=_unroll())
+    (x, aux), _, exit_losses = _scan_layers(
+        cfg, params, body_fn, (x, jnp.zeros((), jnp.float32)))
 
-    xf = apply_norm(x, params["final_norm"], cfg.norm)
+    xf = _head_norm(cfg, x, params["final_norm"])
     w = params.get("exit_w")
     if w is None:  # per-exit heads: final exit = last layer's head
         w = jax.tree.map(lambda l: l[-1], params["layers"])["exit_w"]
@@ -302,16 +495,14 @@ def forward_exits(params, cfg: ModelConfig, batch: Dict[str, Any], *,
 
     def body(carry, inp):
         xx, aux = carry
-        lp, i = inp
+        lp, _, i = inp
         xx, a = _layer_full(cfg, params, lp, xx, positions, i,
                             window=window, backend=backend)
-        pooled = pool_hidden(cfg, apply_norm(xx, lp["exit_norm"], cfg.norm))
-        return (xx, aux + a), pooled
+        pooled = pool_hidden(cfg, _head_norm(cfg, xx, lp["exit_norm"]))
+        return (xx, aux + a), (None, pooled)
 
-    idx = jnp.arange(cfg.num_layers)
-    (x, _), pooled = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (params["layers"], idx),
-        unroll=_unroll())
+    (x, _), _, pooled = _scan_layers(cfg, params, body,
+                                     (x, jnp.zeros((), jnp.float32)))
     # pooled: (L, B, D)
     conf, pred = stacked_exit_confidence(params, cfg, pooled,
                                          conf_backend=conf_backend)
@@ -335,8 +526,10 @@ def stacked_exit_confidence(params, cfg: ModelConfig, pooled, *,
     inside the launch; otherwise they are already normed."""
     ews = _exit_heads(params, cfg)
     if fused_exit:
-        return exit_confidence_fused(pooled, params["layers"]["exit_norm"],
-                                     ews, kind=cfg.norm,
+        norms = params["layers"]["exit_norm"]
+        if cfg.logits_scaling != 1.0:     # norm(h) s / c = norm(h) (s / c)
+            norms = jax.tree.map(lambda a: a / cfg.logits_scaling, norms)
+        return exit_confidence_fused(pooled, norms, ews, kind=cfg.norm,
                                      backend=conf_backend)
     return exit_confidence(pooled, ews, backend=conf_backend)
 
@@ -378,18 +571,16 @@ def forward_exits_masked(params, cfg: ModelConfig, batch: Dict[str, Any],
 
     def body(carry, inp):
         xx = carry
-        lp, i = inp
+        lp, _, i = inp
         xx2, _ = _layer_full(cfg, params, lp, xx, positions, i,
                              window=window, backend=backend)
         xx = jnp.where(i <= live, xx2, xx)
         # the fused epilogue norms inside the confidence program, so the
         # scan only pools the raw carry (pooling commutes with the norm)
-        src = xx if fused_exit else apply_norm(xx, lp["exit_norm"], cfg.norm)
-        return xx, pool_hidden(cfg, src)
+        src = xx if fused_exit else _head_norm(cfg, xx, lp["exit_norm"])
+        return xx, (None, pool_hidden(cfg, src))
 
-    idx = jnp.arange(cfg.num_layers)
-    x, pooled = jax.lax.scan(body, x, (params["layers"], idx),
-                             unroll=_unroll())
+    x, _, pooled = _scan_layers(cfg, params, body, x)
     # pooled: (L, B, D) — per-layer exit pools, frozen past each depth
     conf, pred = stacked_exit_confidence(params, cfg, pooled,
                                          conf_backend=conf_backend,
@@ -404,6 +595,18 @@ def init_caches(cfg: ModelConfig, batch: int, seq_len: int):
     dt = jnp.dtype(cfg.dtype)
     hd = cfg.resolved_head_dim
     window = cfg.effective_window(seq_len) or seq_len
+    if cfg.layer_types:
+        # one stack per state type, as deep as its type has layers
+        n_attn = cfg.layer_kinds().count("attention")
+        st = m2.init_mamba2_state(batch, cfg.d_model, cfg.ssm.state_size,
+                                  cfg.ssm.expand)
+        return {
+            "ssm": jax.tree.map(lambda a: jnp.broadcast_to(
+                a, (cfg.num_layers - n_attn,) + a.shape), st),
+            "attn": jax.tree.map(lambda a: jnp.broadcast_to(
+                a, (n_attn,) + a.shape),
+                attn.init_cache(batch, window, cfg.num_kv_heads, hd, dt)),
+        }
     if cfg.family == "ssm":
         heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
         st = rk.init_rwkv_state(batch, cfg.d_model, heads)
@@ -428,34 +631,36 @@ def init_caches(cfg: ModelConfig, batch: int, seq_len: int):
 
 
 def _layer_decode(cfg: ModelConfig, params, lp, x, cache_slice, cur_index, *,
-                  window: int, occ_caches=None, occ_idx=None):
-    """One-token decode through one layer. Returns (x, new_cache_slice,
-    occ_caches) — occ_* used by hybrid shared attention."""
+                  window: int):
+    """One-token decode through one layer. Returns (x, new_cache_slice)."""
+    if cfg.layer_types:
+        return _typed_block(cfg, lp, x, lambda xn: _typed_mixer_step(
+            cfg, lp, xn, cache_slice, cur_index, window=window))
     if cfg.family == "ssm":
         st = cache_slice
         heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
         h, (tm_last, wkv) = rk.time_mix(
-            lp["tm"], apply_norm(x, lp["ln1"], cfg.norm),
+            lp["tm"], _norm(cfg, x, lp["ln1"]),
             (st["tm_last"], st["wkv"]), num_heads=heads)
         x = x + h
         h, cm_last = rk.channel_mix(
-            lp["cm"], apply_norm(x, lp["ln2"], cfg.norm), st["cm_last"])
+            lp["cm"], _norm(cfg, x, lp["ln2"]), st["cm_last"])
         x = x + h
-        return x, {"tm_last": tm_last, "cm_last": cm_last, "wkv": wkv}, None
+        return x, {"tm_last": tm_last, "cm_last": cm_last, "wkv": wkv}
     if cfg.family == "hybrid":
         st = cache_slice
         h, new_st = m2.mamba2_forward(
-            lp["mamba"], apply_norm(x, lp["ln1"], cfg.norm), st,
+            lp["mamba"], _norm(cfg, x, lp["ln1"]), st,
             state_size=cfg.ssm.state_size, expand=cfg.ssm.expand)
         x = x + h
-        return x, new_st, occ_caches
+        return x, new_st
     h, new_cache = attn.attn_decode(
-        lp["attn"], apply_norm(x, lp["ln1"], cfg.norm), cache_slice,
+        lp["attn"], _norm(cfg, x, lp["ln1"]), cache_slice,
         cur_index, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
         head_dim=cfg.resolved_head_dim, window=window,
-        rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm, mrope=cfg.mrope)
+        rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm, mrope=cfg.mrope)
     x = x + h
-    x2 = apply_norm(x, lp["ln2"], cfg.norm)
+    x2 = _norm(cfg, x, lp["ln2"])
     if cfg.family == "moe":
         # decode is drop-free: capacity covers the all-tokens-to-one-expert
         # worst case (a dropped token at decode would corrupt the stream)
@@ -464,7 +669,19 @@ def _layer_decode(cfg: ModelConfig, params, lp, x, cache_slice, cur_index, *,
                               capacity_factor=float(cfg.moe.num_experts))
     else:
         h = ff.mlp_forward(lp["mlp"], x2, cfg.activation)
-    return x + h, new_cache, None
+    return x + h, new_cache
+
+
+def _embed_step(params, cfg: ModelConfig, token_or_embed):
+    """One decode step's input (B, 1, D): token ids embedded, or an
+    embedding passed in."""
+    if token_or_embed.ndim <= 1 or token_or_embed.dtype in (
+            jnp.int32, jnp.int64):
+        x = jnp.take(params["embed"],
+                     token_or_embed.reshape(-1, 1), axis=0)
+    else:
+        x = token_or_embed.astype(jnp.dtype(cfg.dtype))
+    return _embed_scale(cfg, x)
 
 
 def decode_step(params, cfg: ModelConfig, caches, token_or_embed,
@@ -475,38 +692,33 @@ def decode_step(params, cfg: ModelConfig, caches, token_or_embed,
     every exit (``all_exits`` — SplitEE-S). Returns (logits, conf, pred,
     new_caches).
     """
-    if token_or_embed.ndim <= 1 or token_or_embed.dtype in (
-            jnp.int32, jnp.int64):
-        x = jnp.take(params["embed"],
-                     token_or_embed.reshape(-1, 1), axis=0)
-    else:
-        x = token_or_embed.astype(jnp.dtype(cfg.dtype))
+    x = _embed_step(params, cfg, token_or_embed)
     b = x.shape[0]
     window = cfg.effective_window(window_seq_len)
 
-    if cfg.family == "hybrid":
+    if cfg.family == "hybrid" and not cfg.layer_types:
         k = cfg.hybrid_attn_every
         sp = params["shared_attn"]
 
         def body(carry, inp):
             xx, occ = carry
             lp, st, i = inp
-            xx, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                          window=window)
+            xx, new_st = _layer_decode(cfg, params, lp, xx, st, cur_index,
+                                       window=window)
 
             def with_attn(args):
                 xx, occ = args
                 oi = (i + 1) // k - 1
                 sl = jax.tree.map(lambda a: a[oi], occ)
                 h, new_sl = attn.attn_decode(
-                    sp["attn"], apply_norm(xx, sp["ln1"], cfg.norm), sl,
+                    sp["attn"], _norm(cfg, xx, sp["ln1"]), sl,
                     cur_index, num_heads=cfg.num_heads,
                     num_kv_heads=cfg.num_kv_heads,
                     head_dim=cfg.resolved_head_dim, window=window,
-                    rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
+                    rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm)
                 xx = xx + h
                 xx = xx + ff.mlp_forward(
-                    sp["mlp"], apply_norm(xx, sp["ln2"], cfg.norm),
+                    sp["mlp"], _norm(cfg, xx, sp["ln2"]),
                     cfg.activation)
                 occ = jax.tree.map(
                     lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
@@ -515,8 +727,7 @@ def decode_step(params, cfg: ModelConfig, caches, token_or_embed,
 
             xx, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
                                    with_attn, lambda a: a, (xx, occ))
-            pooled = pool_hidden(cfg, apply_norm(xx, lp["exit_norm"],
-                                                 cfg.norm))
+            pooled = pool_hidden(cfg, _head_norm(cfg, xx, lp["exit_norm"]))
             return (xx, occ), (new_st, pooled)
 
         idx = jnp.arange(cfg.num_layers)
@@ -525,21 +736,14 @@ def decode_step(params, cfg: ModelConfig, caches, token_or_embed,
             unroll=_unroll())
         new_caches = {"ssm": new_ssm, "attn": occ}
     else:
-        cache_key = "ssm" if cfg.family == "ssm" else "attn"
-
         def body(xx, inp):
             lp, st, i = inp
-            xx, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                          window=window)
-            pooled = pool_hidden(cfg, apply_norm(xx, lp["exit_norm"],
-                                                 cfg.norm))
+            xx, new_st = _layer_decode(cfg, params, lp, xx, st, cur_index,
+                                       window=window)
+            pooled = pool_hidden(cfg, _head_norm(cfg, xx, lp["exit_norm"]))
             return xx, (new_st, pooled)
 
-        idx = jnp.arange(cfg.num_layers)
-        x, (new_st, pooled) = jax.lax.scan(
-            body, x, (params["layers"], caches[cache_key], idx),
-            unroll=_unroll())
-        new_caches = {cache_key: new_st}
+        x, new_caches, pooled = _scan_layers(cfg, params, body, x, caches)
 
     # exit observables (post-scan: one gather + one fused confidence call)
     shared = cfg.exits.share_head or not cfg.exits.enabled
@@ -559,7 +763,7 @@ def decode_step(params, cfg: ModelConfig, caches, token_or_embed,
     else:
         conf = pred = None
 
-    xf = apply_norm(x, params["final_norm"], cfg.norm)
+    xf = _head_norm(cfg, x, params["final_norm"])
     logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
     return logits, conf, pred, new_caches
 
@@ -583,63 +787,76 @@ def _decode_layer_range(params, cfg: ModelConfig, caches, x, cur_index,
     a dynamic index and the slice is written back in place into the carried
     tree, so the loop neither computes nor touches the layers outside the
     range (the caches are not donated, so XLA copies the input tree into
-    the carry once).
+    the carry once). Typed layers run one loop per run of one type
+    (`_runs`), each over its part of the range.
 
     Returns (x, new_caches, buf): with ``record``, ``buf`` (L, B, 1, D)
     holds the carry after each layer that ran (zeros from ``hi`` up).
     """
-    hybrid = cfg.family == "hybrid"
-    key = "ssm" if cfg.family in ("ssm", "hybrid") else "attn"
+    hybrid = cfg.family == "hybrid" and not cfg.layer_types
     take = functools.partial(jax.lax.dynamic_index_in_dim, keepdims=False)
     put = jax.lax.dynamic_update_index_in_dim
     if hybrid:
         k = cfg.hybrid_attn_every
         sp = params["shared_attn"]
 
-    def body(i, carry):
-        xx, stack, occ, buf = carry
-        lp = jax.tree.map(lambda a: take(a, i, 0), params["layers"])
-        st = jax.tree.map(lambda a: take(a, i, 0), stack)
-        m = live(i)
-        xx2, new_st, _ = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                       window=window)
-        if hybrid:
-            def with_attn(args):
-                xx2, occ = args
-                oi = (i + 1) // k - 1
-                sl = jax.tree.map(lambda a: a[oi], occ)
-                h, new_sl = attn.attn_decode(
-                    sp["attn"], apply_norm(xx2, sp["ln1"], cfg.norm), sl,
-                    cur_index, num_heads=cfg.num_heads,
-                    num_kv_heads=cfg.num_kv_heads,
-                    head_dim=cfg.resolved_head_dim, window=window,
-                    rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
-                xx2 = xx2 + h
-                xx2 = xx2 + ff.mlp_forward(
-                    sp["mlp"], apply_norm(xx2, sp["ln2"], cfg.norm),
-                    cfg.activation)
-                # shared cache: advance only the rows this layer advances
-                new_sl = _mask_rows(m, new_sl, sl)
-                occ = jax.tree.map(lambda buf, ns: put(buf, ns, oi, 0),
-                                   occ, new_sl)
-                return xx2, occ
+    def run_body(kind, off):
+        # layer i's state is entry i + off of its stack
+        def body(i, carry):
+            xx, stack, occ, buf = carry
+            j = i if off == 0 else i + off
+            lp = _layer_params(params, kind, i, j)
+            st = jax.tree.map(lambda a: take(a, j, 0), stack)
+            m = live(i)
+            xx2, new_st = _layer_decode(cfg, params, lp, xx, st, cur_index,
+                                        window=window)
+            if hybrid:
+                def with_attn(args):
+                    xx2, occ = args
+                    oi = (i + 1) // k - 1
+                    sl = jax.tree.map(lambda a: a[oi], occ)
+                    h, new_sl = attn.attn_decode(
+                        sp["attn"], _norm(cfg, xx2, sp["ln1"]), sl,
+                        cur_index, num_heads=cfg.num_heads,
+                        num_kv_heads=cfg.num_kv_heads,
+                        head_dim=cfg.resolved_head_dim, window=window,
+                        rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm)
+                    xx2 = xx2 + h
+                    xx2 = xx2 + ff.mlp_forward(
+                        sp["mlp"], _norm(cfg, xx2, sp["ln2"]),
+                        cfg.activation)
+                    # shared cache: advance only the rows this layer advances
+                    new_sl = _mask_rows(m, new_sl, sl)
+                    occ = jax.tree.map(lambda buf, ns: put(buf, ns, oi, 0),
+                                       occ, new_sl)
+                    return xx2, occ
 
-            xx2, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
-                                    with_attn, lambda a: a, (xx2, occ))
-        xx = jnp.where(m[:, None, None], xx2, xx)
-        new_st = _mask_rows(m, new_st, st)
-        stack = jax.tree.map(lambda a, s: put(a, s, i, 0), stack, new_st)
-        if record:
-            buf = put(buf, xx, i, 0)
-        return xx, stack, occ, buf
+                xx2, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
+                                        with_attn, lambda a: a, (xx2, occ))
+            xx = jnp.where(m[:, None, None], xx2, xx)
+            new_st = _mask_rows(m, new_st, st)
+            stack = jax.tree.map(lambda a, s: put(a, s, j, 0), stack, new_st)
+            if record:
+                buf = put(buf, xx, i, 0)
+            return xx, stack, occ, buf
+        return body
 
     buf = (jnp.zeros((cfg.num_layers,) + x.shape, x.dtype) if record
            else None)
     occ = caches["attn"] if hybrid else None
+    new_caches = dict(caches)
     with jax.named_scope("splitee.layers"):
-        x, stack, occ, buf = jax.lax.fori_loop(
-            lo, hi, body, (x, caches[key], occ, buf))
-    new_caches = {"ssm": stack, "attn": occ} if hybrid else {key: stack}
+        for kind, a, b, s in _runs(cfg):
+            key = _state_key(cfg, kind)
+            # a run of typed layers loops over its part of lo .. hi-1 (none
+            # where they do not meet)
+            bounds = (lo, hi) if kind is None else (jnp.clip(lo, a, b),
+                                                    jnp.clip(hi, a, b))
+            x, new_caches[key], occ, buf = jax.lax.fori_loop(
+                *bounds, run_body(kind, s - a),
+                (x, new_caches[key], occ, buf))
+    if hybrid:
+        new_caches["attn"] = occ
     return x, new_caches, buf
 
 
@@ -662,12 +879,7 @@ def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
     ``hidden`` is the raw carry after each sample's own split layer, the
     payload a mid-generation offload ships to the cloud.
     """
-    if token_or_embed.ndim <= 1 or token_or_embed.dtype in (
-            jnp.int32, jnp.int64):
-        x = jnp.take(params["embed"],
-                     token_or_embed.reshape(-1, 1), axis=0)
-    else:
-        x = token_or_embed.astype(jnp.dtype(cfg.dtype))
+    x = _embed_step(params, cfg, token_or_embed)
     L = cfg.num_layers
     hi = jnp.minimum(jnp.max(depths) + 1, L)
     x, new_caches, buf = _decode_layer_range(
@@ -678,15 +890,15 @@ def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
     # its final carry above it (where the masked rows stood still)
     ran = jnp.arange(L)[:, None] <= depths[None, :]
     hs = jnp.where(ran[:, :, None, None], buf, x[None])
-    pooled = jax.vmap(lambda h, n: pool_hidden(cfg, apply_norm(
-        h, n, cfg.norm)))(hs, params["layers"]["exit_norm"])
+    pooled = jax.vmap(lambda h, n: pool_hidden(cfg, _head_norm(
+        cfg, h, n)))(hs, params["layers"]["exit_norm"])
     with jax.named_scope("splitee.exit_heads"):
         conf, pred = stacked_exit_confidence(params, cfg, pooled,
                                              conf_backend=conf_backend)
     with jax.named_scope("splitee.final_head"):
         ews = _exit_heads(params, cfg)
         ew = ews if ews.ndim == 2 else ews[-1]       # final exit's head
-        xf = apply_norm(x, params["final_norm"], cfg.norm)
+        xf = _head_norm(cfg, x, params["final_norm"])
         logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
     return logits, conf, pred, x, new_caches
 
@@ -716,7 +928,7 @@ def decode_step_resume(params, cfg: ModelConfig, caches, hidden,
     with jax.named_scope("splitee.final_head"):
         ew = params["exit_w"] if "exit_w" in params \
             else params["layers"]["exit_w"][-1]
-        xf = apply_norm(x, params["final_norm"], cfg.norm)
+        xf = _head_norm(cfg, x, params["final_norm"])
         logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
     return logits, new_caches
 
@@ -735,19 +947,28 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, Any], *,
     window = cfg.effective_window(seq_total)
     cache_window = window or seq_total
 
-    if cfg.family == "ssm":
+    if cfg.layer_types:
+        def body(xx, inp):
+            lp, _, _ = inp
+            xx, st = _typed_block(cfg, lp, xx, lambda xn: _typed_mixer_seq(
+                cfg, lp, xn, positions, window=window, backend=backend,
+                cache_window=cache_window))
+            return constrain(xx, "batch", None, None), (st, None)
+
+        x, caches, _ = _scan_layers(cfg, params, body, x)
+    elif cfg.family == "ssm":
         def body(carry, inp):
             xx = carry
             lp, i = inp
             heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
             st = rk.init_rwkv_state(b, cfg.d_model, heads)
             h, (tm_last, wkv) = rk.time_mix(
-                lp["tm"], apply_norm(xx, lp["ln1"], cfg.norm),
+                lp["tm"], _norm(cfg, xx, lp["ln1"]),
                 (st["tm_last"], st["wkv"]), num_heads=heads, backend=backend,
                 chunk=cfg.ssm.chunk_size)
             xx = xx + h
             h, cm_last = rk.channel_mix(
-                lp["cm"], apply_norm(xx, lp["ln2"], cfg.norm), st["cm_last"])
+                lp["cm"], _norm(cfg, xx, lp["ln2"]), st["cm_last"])
             xx = xx + h
             return xx, {"tm_last": tm_last, "cm_last": cm_last, "wkv": wkv}
 
@@ -770,7 +991,7 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, Any], *,
             st = m2.init_mamba2_state(b, cfg.d_model, cfg.ssm.state_size,
                                       cfg.ssm.expand)
             h, new_st = m2.mamba2_forward(
-                lp["mamba"], apply_norm(xx, lp["ln1"], cfg.norm), st,
+                lp["mamba"], _norm(cfg, xx, lp["ln1"]), st,
                 state_size=cfg.ssm.state_size, expand=cfg.ssm.expand,
                 chunk=cfg.ssm.chunk_size)
             xx = xx + h
@@ -779,15 +1000,15 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, Any], *,
                 xx, occ = args
                 oi = (i + 1) // k - 1
                 h2, (kk, vv) = attn.attn_prefill(
-                    sp["attn"], apply_norm(xx, sp["ln1"], cfg.norm),
+                    sp["attn"], _norm(cfg, xx, sp["ln1"]),
                     positions, num_heads=cfg.num_heads,
                     num_kv_heads=cfg.num_kv_heads,
                     head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-                    window=window, rope_theta=cfg.rope_theta,
+                    window=window, rope_theta=cfg.attn_rope_theta,
                     qk_norm=cfg.qk_norm, backend=backend, return_kv=True)
                 xx = xx + h2
                 xx = xx + ff.mlp_forward(
-                    sp["mlp"], apply_norm(xx, sp["ln2"], cfg.norm),
+                    sp["mlp"], _norm(cfg, xx, sp["ln2"]),
                     cfg.activation)
                 sl = jax.tree.map(lambda a: a[oi], occ)
                 sl = attn.fill_cache(sl, kk[:, -cache_window:],
@@ -811,14 +1032,14 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, Any], *,
         def body(xx, inp):
             lp, i = inp
             h, (kk, vv) = attn.attn_prefill(
-                lp["attn"], apply_norm(xx, lp["ln1"], cfg.norm), positions,
+                lp["attn"], _norm(cfg, xx, lp["ln1"]), positions,
                 num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
                 head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-                window=window, rope_theta=cfg.rope_theta,
+                window=window, rope_theta=cfg.attn_rope_theta,
                 qk_norm=cfg.qk_norm, mrope=cfg.mrope, backend=backend,
                 return_kv=True)
             xx = xx + h
-            x2 = apply_norm(xx, lp["ln2"], cfg.norm)
+            x2 = _norm(cfg, xx, lp["ln2"])
             if cfg.family == "moe":
                 h, _ = ff.moe_forward(
                     lp["moe"], x2, num_experts=cfg.moe.num_experts,
@@ -841,6 +1062,6 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, Any], *,
 
     ew = params["exit_w"] if "exit_w" in params \
         else params["layers"]["exit_w"][-1]
-    xf = apply_norm(x, params["final_norm"], cfg.norm)
+    xf = _head_norm(cfg, x, params["final_norm"])
     logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
     return logits, caches

@@ -229,8 +229,11 @@ class _DecodeSession:
                 arms = np.asarray(self.ctl.choose_splits(B), np.int64)
             depths_dev = jnp.asarray(arms, jnp.int32)
         # layers each program's loop runs: the edge's stops at the deepest
-        # split, the cloud's starts above the shallowest offloaded one
-        tr.count("splitee.decode.edge_layers", int(arms.max()) + 1)
+        # split, the cloud's starts above the shallowest offloaded one; and
+        # the cache state those layers carry
+        hi = int(arms.max()) + 1
+        tr.count("splitee.decode.edge_layers", hi)
+        tr.count("splitee.decode.state_bytes", int(mgr.layer_bytes[:hi].sum()))
         with tr.span("splitee.decode.edge"):
             (_, conf_all, pred_all, conf_fin, pred_fin, hidden,
              new_caches) = self.runtime.edge_fn(
@@ -263,8 +266,10 @@ class _DecodeSession:
         if offload_rows:
             tr.count("splitee.decode.cloud_launches")
             tr.count("splitee.decode.offload_rows", len(offload_rows))
-            tr.count("splitee.decode.cloud_layers",
-                     L - 1 - int(arms[offload_rows].min()))
+            lo = int(arms[offload_rows].min()) + 1
+            tr.count("splitee.decode.cloud_layers", L - lo)
+            tr.count("splitee.decode.state_bytes",
+                     int(mgr.layer_bytes[lo:].sum()))
             with tr.span("splitee.decode.codec"):
                 rows = np.asarray(offload_rows, np.int64)
                 hidden_np = np.asarray(hidden)
@@ -288,6 +293,7 @@ class _DecodeSession:
             obs: List[int] = [0] * B
             if offload_rows:
                 bytes_rows = mgr.meter(rows, arms, hid_wire)
+                tr.count("splitee.decode.offload_bytes", int(bytes_rows.sum()))
                 for j, b in enumerate(rows):
                     conf_Ls[b] = float(conf_L_np[b])
                     obs[b] = int(bytes_rows[j])

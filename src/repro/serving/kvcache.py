@@ -33,6 +33,11 @@ cache (attention: one K/V slot + 4 pos bytes per layer; ssm/hybrid: the
 full recurrent state per layer), and ``offload_scale_vec`` turns that into
 the per-arm wire/raw ratio the controller folds into the paper's
 communication term ``o`` — deeper splits ship strictly more slice bytes.
+Which layers hold which state follows the family: every layer its own
+(dense, moe, ssm); a Mamba2 state per layer plus the shared block's K/V
+after every k-th layer (zamba2); or, with ``layer_types``, each layer the
+state of its own mixer (granite-4.0-h: a Mamba2 layer its SSM and conv
+state, an attention layer its K/V).
 """
 from __future__ import annotations
 
@@ -45,6 +50,31 @@ from repro.configs.base import ModelConfig
 from repro.serving.offload_codec import OffloadCodec
 
 
+def _state_layers(cfg: ModelConfig, key: str) -> np.ndarray:
+    """(L,) bool: the layers that hold an entry of cache stack ``key``
+    ("ssm" or "attn")."""
+    L = cfg.num_layers
+    if cfg.layer_types:
+        attn = np.array([t == "attention" for t in cfg.layer_kinds()])
+        return attn if key == "attn" else ~attn
+    if cfg.family == "hybrid" and key == "attn":
+        k = cfg.hybrid_attn_every
+        return np.arange(L) % k == k - 1
+    return np.ones(L, bool)
+
+
+def layer_state_bytes(cfg: ModelConfig, caches) -> np.ndarray:
+    """(L,) bytes of each layer's part of a cache tree (or of its
+    ``jax.eval_shape``): one entry of the stack of each state it holds,
+    over every row."""
+    out = np.zeros(cfg.num_layers, np.int64)
+    for key, stack in caches.items():
+        out[_state_layers(cfg, key)] += sum(
+            int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
+            for leaf in jax.tree.leaves(stack))
+    return out
+
+
 def per_step_layer_bytes(cfg: ModelConfig) -> np.ndarray:
     """(L,) bytes each layer adds to its cache per decode step.
 
@@ -53,25 +83,8 @@ def per_step_layer_bytes(cfg: ModelConfig) -> np.ndarray:
     real cache dtypes/shapes for every family without reimplementing them.
     """
     from repro.models import transformer
-    shapes = jax.eval_shape(lambda: transformer.init_caches(cfg, 1, 1))
-    L = cfg.num_layers
-    out = np.zeros(L, np.int64)
-    ssm = shapes.get("ssm")
-    if ssm is not None:
-        out[:] += sum(
-            int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
-            for leaf in jax.tree.leaves(ssm))
-    at = shapes.get("attn")
-    if at is not None:
-        per = sum(
-            int(np.prod(leaf.shape[1:])) * leaf.dtype.itemsize
-            for leaf in jax.tree.leaves(at))
-        if cfg.family == "hybrid":
-            k = cfg.hybrid_attn_every
-            out[np.arange(L) % k == k - 1] += per
-        else:
-            out[:] += per
-    return out
+    return layer_state_bytes(cfg, jax.eval_shape(
+        lambda: transformer.init_caches(cfg, 1, 1)))
 
 
 def step_slice_bytes(cfg: ModelConfig, depth: int) -> int:
@@ -121,6 +134,9 @@ class DecodeCacheManager:
         b = int(jax.tree.leaves(caches)[0].shape[1])
         self.batch = b
         self._slice_cum = np.cumsum(per_step_layer_bytes(cfg))
+        # (L,) bytes of each layer's whole state over the batch: what a
+        # layer loop that runs the layer carries through it
+        self.layer_bytes = layer_state_bytes(cfg, caches)
         self.realized_depths: List[np.ndarray] = []   # (B,) per step
         self.offloaded: List[np.ndarray] = []         # (B,) bool per step
         self.offloads_per_seq = np.zeros(b, np.int64)

@@ -23,7 +23,7 @@ def _lm_logits_full(model, params, tokens):
 
 @pytest.mark.parametrize("arch", ["granite-3-2b", "qwen3-1.7b",
                                   "rwkv6-3b", "zamba2-1.2b",
-                                  "mixtral-8x22b"])
+                                  "mixtral-8x22b", "granite-4.0-h-micro"])
 def test_prefill_then_decode_matches_full_forward(arch):
     cfg = f32_cfg(get_smoke_config(arch))
     if cfg.moe is not None:

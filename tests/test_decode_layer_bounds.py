@@ -20,8 +20,12 @@ from repro.models.api import build_model
 from repro.models.common import apply_norm
 from repro.sharding import constrain
 
-ARCHS = ["qwen3-1.7b", "rwkv6-3b", "zamba2-1.2b"]   # dense, ssm, hybrid
+# dense, ssm, hybrid with a shared block, hybrid with typed layers
+ARCHS = ["qwen3-1.7b", "rwkv6-3b", "zamba2-1.2b", "granite-4.0-h-micro"]
 L, B, S, TOTAL = 4, 4, 3, 6
+# the typed hybrid's pattern at depth L: the mixed depths below exit at
+# Mamba layers 0 and 1 and skip the attention layer 2 in some rows
+TYPES = ("mamba", "mamba", "attention", "mamba")
 
 
 def _shared_attn(cfg, params, xx2, occ, i, m, cur_index, window):
@@ -55,40 +59,40 @@ def _shared_attn(cfg, params, xx2, occ, i, m, cur_index, window):
 
 def _full_sweep(params, cfg, caches, x, cur_index, live, window):
     """Every layer, rows outside ``live(i)`` masked: (x, caches, pooled)."""
-    hybrid = cfg.family == "hybrid"
-    key = "ssm" if cfg.family in ("ssm", "hybrid") else "attn"
+    hybrid = cfg.family == "hybrid" and not cfg.layer_types
 
     def body(carry, inp):
         xx, occ = carry
         lp, st, i = inp
         m = live(i)
-        xx2, new_st, _ = tf._layer_decode(cfg, params, lp, xx, st,
-                                          cur_index, window=window)
+        xx2, new_st = tf._layer_decode(cfg, params, lp, xx, st, cur_index,
+                                       window=window)
         if hybrid:
             xx2, occ = _shared_attn(cfg, params, xx2, occ, i, m, cur_index,
                                     window)
         xx = jnp.where(m[:, None, None], xx2, xx)
         new_st = tf._mask_rows(m, new_st, st)
-        pooled = tf.pool_hidden(cfg, apply_norm(xx, lp["exit_norm"],
-                                                cfg.norm))
+        pooled = tf.pool_hidden(cfg, tf._head_norm(cfg, xx,
+                                                   lp["exit_norm"]))
         return (xx, occ), (new_st, pooled)
 
     occ = caches["attn"] if hybrid else None
-    (x, occ), (stack, pooled) = jax.lax.scan(
-        body, (x, occ), (params["layers"], caches[key], jnp.arange(L)))
-    return x, ({"ssm": stack, "attn": occ} if hybrid else {key: stack}), \
-        pooled
+    (x, occ), new_caches, pooled = tf._scan_layers(cfg, params, body,
+                                                   (x, occ), caches)
+    if hybrid:
+        new_caches["attn"] = occ
+    return x, new_caches, pooled
 
 
 def _head(params, cfg, x):
     ews = tf._exit_heads(params, cfg)
     ew = ews if ews.ndim == 2 else ews[-1]
-    xf = apply_norm(x, params["final_norm"], cfg.norm)
+    xf = tf._head_norm(cfg, x, params["final_norm"])
     return constrain(xf[:, -1, :] @ ew, "batch", "model")
 
 
 def oracle_masked(params, cfg, caches, token, cur_index, depths):
-    x = jnp.take(params["embed"], token.reshape(-1, 1), axis=0)
+    x = tf._embed_step(params, cfg, token)
     x, new_caches, pooled = _full_sweep(
         params, cfg, caches, x, cur_index, lambda i: i <= depths,
         cfg.effective_window(TOTAL))
@@ -111,6 +115,8 @@ def _bed(arch):
     if arch not in _BEDS:
         cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32",
                                   num_layers=L)
+        if cfg.layer_types:
+            cfg = dataclasses.replace(cfg, layer_types=TYPES)
         params = build_model(cfg).init(jax.random.PRNGKey(1))
         prompts = np.random.default_rng(5).integers(
             0, cfg.vocab_size, size=(B, S)).astype(np.int32)

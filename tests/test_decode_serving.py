@@ -481,7 +481,8 @@ def test_codec_decode_run_meters_encoded_bytes():
 
 # ------------------------------------------------------ kvcache closed forms
 
-@pytest.mark.parametrize("arch", ARCHS + ["zamba2-1.2b"])
+@pytest.mark.parametrize("arch", ARCHS + ["zamba2-1.2b",
+                                          "granite-4.0-h-micro"])
 def test_per_step_bytes_match_real_cache_growth(arch):
     """The closed-form per-layer step bytes must equal the real cache's
     per-step footprint: summing all layers reproduces total cache bytes

@@ -11,7 +11,7 @@ from repro.models.api import build_model
 from repro.optim import adamw_init, adamw_update
 from repro.optim.adamw import AdamWConfig
 
-ALL = ASSIGNED_ARCHS + ["elasticbert12"]
+ALL = ASSIGNED_ARCHS + ["elasticbert12", "granite-4.0-h-micro"]
 
 
 @pytest.mark.parametrize("arch", ALL)
