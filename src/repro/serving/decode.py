@@ -15,9 +15,11 @@ either
   masked select — see serving/kvcache.py for the consistency contract), or
 * **offloads** — the split-layer hidden ships through the
   :class:`OffloadCodec` round trip (the cloud computes on the
-  reconstruction, so quantization loss is visible end to end) together
-  with the per-step ≤ℓ cache-slice bytes; ``decode_step_resume`` completes
-  layers > ℓ for exactly the offloaded samples (its loop starts above the
+  reconstruction, so quantization loss is visible end to end; with no
+  codec set, the identity, the cloud reads the edge's own device array
+  and the payload never leaves the device) together with the per-step
+  ≤ℓ cache-slice bytes; ``decode_step_resume`` completes layers > ℓ for
+  exactly the offloaded samples (its loop starts above the
   shallowest offloaded split: the layers below are skipped, not masked)
   and its returned tree — bitwise the input everywhere it did not
   advance — re-syncs the edge cache on commit.
@@ -25,6 +27,12 @@ either
 The cloud call blocks: unlike the classifier's deferred flush queue, step
 t+1 cannot start until t's token exists — the serial dependency is
 inherent to autoregressive decode, so there is nothing to overlap with.
+Results come back to the host once per program: as soon as the edge (or
+the cloud) is dispatched, the copies of the outputs the host decides on
+are queued behind it on the device, and the host waits once, for all of
+them together, in ``edge_wait`` (``cloud_wait``). The exit decision stays
+on the host and reads what the programs return.
+
 One bandit round per decode step; the communication term is per-arm (an
 (L,) ``offload_scale`` — deeper splits ship strictly more cache slice).
 
@@ -53,11 +61,22 @@ from repro.core.controller import SplitEEController
 from repro.core.rewards import CostModel
 from repro.data.stream import microbatches
 from repro.models import transformer
-from repro.serving.kvcache import DecodeCacheManager, offload_scale_vec
+from repro.serving.kvcache import (DecodeCacheManager, hidden_raw_bytes,
+                                   offload_scale_vec)
 from repro.serving.offload_codec import OffloadCodec
 from repro.serving.tracing import Tracer
 
 PyTree = Any
+
+
+def _queue_to_host(*arrays):
+    """Queue each device array's copy to the host behind the program that
+    makes it, and return the arrays; the host reads them later, all in
+    one `jax.device_get`."""
+    for a in arrays:
+        if isinstance(a, jax.Array):
+            a.copy_to_host_async()
+    return arrays
 
 
 @dataclasses.dataclass
@@ -217,7 +236,16 @@ class _DecodeSession:
         """One token round at position ``step``: select → masked edge →
         per-row exit/offload → blocking cloud resume → fold. Writes round
         ``t``'s tokens and exits into ``gen``/``exited_steps`` and returns
-        the next round's input token on the device."""
+        the next round's input token on the device.
+
+        Each program's host-bound outputs (the exit confidences and
+        tokens) have their copies queued at its dispatch and are read in
+        one fetch, so the host waits once per program. The offload payload
+        goes to the cloud as the edge's ``hidden`` device array under the
+        identity codec; only a real codec reads it on the host (its copy
+        queued with the edge's outputs) and uploads the decoded rows. The
+        tracer's ``splitee.decode.host_fetches`` counts the blocking
+        fetches."""
         tr = self.tracer
         B = gen.shape[0]
         L = self.cost.num_layers
@@ -239,11 +267,13 @@ class _DecodeSession:
              new_caches) = self.runtime.edge_fn(
                 self.params, mgr.caches, tok, step, depths_dev, total)
             mgr.commit_edge(new_caches, arms)
+            edge_out = _queue_to_host(conf_all, pred_all, conf_fin, pred_fin)
+            if mgr.codec is not None:
+                _queue_to_host(hidden)
         with tr.span("splitee.decode.edge_wait"):
-            conf_np = np.asarray(conf_all)            # (L, B)
-            pred_np = np.asarray(pred_all)
-            conf_fin_np = np.asarray(conf_fin)
-            pred_fin_np = np.asarray(pred_fin)
+            tr.count("splitee.decode.host_fetches")
+            conf_np, pred_np, conf_fin_np, pred_fin_np = jax.device_get(
+                edge_out)                             # conf, pred: (L, B)
 
         # at the final arm there is no split: confidence and token come
         # from the LM head itself, so forced-final decode IS plain
@@ -272,21 +302,29 @@ class _DecodeSession:
                      int(mgr.layer_bytes[lo:].sum()))
             with tr.span("splitee.decode.codec"):
                 rows = np.asarray(offload_rows, np.int64)
-                hidden_np = np.asarray(hidden)
-                dec_rows, hid_wire = mgr.ship_hidden(hidden_np, rows)
-                hid_in = hidden_np.copy()
-                hid_in[rows] = dec_rows
+                if mgr.codec is None:
+                    # the identity codec: the cloud reads the edge's own
+                    # payload, which never leaves the device
+                    hid_dev, hid_wire = hidden, hidden_raw_bytes(mgr.cfg)
+                else:
+                    tr.count("splitee.decode.host_fetches")
+                    hidden_np = np.asarray(hidden)
+                    dec_rows, hid_wire = mgr.ship_hidden(hidden_np, rows)
+                    hid_in = hidden_np.copy()
+                    hid_in[rows] = dec_rows
+                    hid_dev = jnp.asarray(hid_in)
                 active = np.zeros(B, bool)
                 active[rows] = True
-                hid_dev, active_dev = jnp.asarray(hid_in), jnp.asarray(active)
+                active_dev = jnp.asarray(active)
             with tr.span("splitee.decode.cloud"):
                 _, conf_L_d, pred_L_d, new_caches = self.runtime.cloud_fn(
                     self.params, mgr.caches, hid_dev, step, depths_dev,
                     active_dev, total)
                 mgr.commit_cloud(new_caches, active)
+                cloud_out = _queue_to_host(conf_L_d, pred_L_d)
             with tr.span("splitee.decode.cloud_wait"):
-                conf_L_np = np.asarray(conf_L_d)
-                pred_L_np = np.asarray(pred_L_d)
+                tr.count("splitee.decode.host_fetches")
+                conf_L_np, pred_L_np = jax.device_get(cloud_out)
 
         with tr.span("splitee.decode.fold"):
             conf_Ls: List[Optional[float]] = [None] * B

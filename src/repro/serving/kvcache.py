@@ -19,7 +19,8 @@ classifier stream never had:
 * **Mid-generation offload** — the edge ships the split-layer hidden
   through the :class:`OffloadCodec` (a real encode/decode round trip: what
   the cloud computes on is the *reconstruction*, so quantization error is
-  visible in the outputs, exactly like the classifier runtimes) plus the
+  visible in the outputs, exactly like the classifier runtimes; the
+  identity codec ships the device array as it is) plus the
   per-step ≤ℓ cache-slice update at raw bytes (the cloud needs layers ≤ ℓ
   current to keep decoding; the slice is structured state, shipped
   unquantized). The cloud half (``decode_step_resume``) advances only
@@ -169,11 +170,11 @@ class DecodeCacheManager:
         samples. Returns ``(decoded_rows, hidden_wire_bytes_per_row)`` —
         the cloud consumes the *decoded* payload, so codec loss is visible
         end to end. With ``error_feedback`` the per-sequence residual is
-        folded in and updated; without a codec this is a bitwise copy.
+        folded in and updated. Needs a codec: under the identity the
+        session hands the cloud the edge's device array itself, metered at
+        ``hidden_raw_bytes``.
         """
         sel = hidden[rows]
-        if self.codec is None:
-            return sel.copy(), hidden_raw_bytes(self.cfg)
         if self._residual is not None:
             enc, decoded, new_res = self.codec.encode_with_feedback(
                 sel, self._residual[rows])

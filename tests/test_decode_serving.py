@@ -27,6 +27,7 @@ The suite is the subsystem's bit-identity ladder:
 Plus report-shape/accounting sanity and the `ServingConfig` decode
 validation surface.
 """
+import copy
 import dataclasses
 import glob
 
@@ -477,6 +478,78 @@ def test_codec_decode_run_meters_encoded_bytes():
                  for d in depths_off)
     assert rep.offload_bytes == dec["wire_bytes_per_sequence"].sum() \
         == expect
+
+
+# ------------------------------------------------ host <-> device schedule
+
+def _spied(rt):
+    """A copy of the runtime (the same jitted programs) whose edge and
+    cloud calls record, by step, the ``hidden`` the edge returned and the
+    ``hidden``/``active`` the cloud received."""
+    spy = copy.copy(rt)
+    edge_hidden, cloud_in = {}, {}
+
+    def edge_fn(params, caches, tok, step, depths, total):
+        out = rt.edge_fn(params, caches, tok, step, depths, total)
+        edge_hidden[step] = out[5]
+        return out
+
+    def cloud_fn(params, caches, hidden, step, depths, active, total):
+        cloud_in[step] = (hidden, np.asarray(active))
+        return rt.cloud_fn(params, caches, hidden, step, depths, active,
+                           total)
+
+    spy.edge_fn, spy.cloud_fn = edge_fn, cloud_fn
+    return spy, edge_hidden, cloud_in
+
+
+def _spied_push(codec, seed=9):
+    cfg, params, rt, cost = _bed(ARCHS[0])
+    spy, edge_hidden, cloud_in = _spied(rt)
+    sess = _DecodeSession(spy, params, cost, batch_size=4,
+                          max_new_tokens=T, codec=codec)
+    sess.push(_prompts(cfg, 4, seed=seed))
+    assert cloud_in, "no round offloaded: the test needs a cloud launch"
+    return sess, edge_hidden, cloud_in
+
+
+def test_identity_codec_payload_stays_on_the_device():
+    """Without a codec the cloud is handed the very device array the
+    edge returned: no host copy, no upload."""
+    sess, edge_hidden, cloud_in = _spied_push(None)
+    for step, (hidden, _) in cloud_in.items():
+        assert hidden is edge_hidden[step]
+    cnt = sess.tracer.snapshot()["counts"]
+    assert cnt["splitee.decode.cloud_launches"] == len(cloud_in)
+
+
+def test_codec_cloud_reads_the_decoded_rows():
+    """With an int8 codec the cloud reads the codec's reconstruction in
+    the offloaded rows and the edge's hidden, bit for bit, in the rest."""
+    codec = OffloadCodec(quant="int8")
+    _, edge_hidden, cloud_in = _spied_push(codec)
+    for step, (hidden, active) in cloud_in.items():
+        raw = np.asarray(edge_hidden[step])
+        got = np.asarray(hidden)
+        assert hidden is not edge_hidden[step]
+        want = codec.decode(codec.encode(raw[active])).astype(raw.dtype)
+        np.testing.assert_array_equal(got[active], want)
+        np.testing.assert_array_equal(got[~active], raw[~active])
+        assert not np.array_equal(got[active], raw[active])   # lossy
+
+
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_host_fetches_count_the_blocking_reads(quant):
+    """One blocking fetch per program run (the edge's every round, the
+    cloud's every launch), plus the host read of the hidden per launch
+    where a codec needs it."""
+    codec = None if quant == "none" else OffloadCodec(quant=quant)
+    sess, _, cloud_in = _spied_push(codec)
+    cnt = sess.tracer.snapshot()["counts"]
+    steps, launches = cnt["splitee.decode.steps"], len(cloud_in)
+    assert steps == T
+    per_launch = 1 if codec is None else 2
+    assert cnt["splitee.decode.host_fetches"] == steps + per_launch * launches
 
 
 # ------------------------------------------------------ kvcache closed forms
