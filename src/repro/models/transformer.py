@@ -17,6 +17,14 @@ layers; granite-4.0-h: ``layer_types`` names each layer's mixer, Mamba2 or
 attention, each layer with its own mixer and MLP). Enc-dec (seamless)
 wraps this module — see encdec.py.
 
+Each family's layer is written once per mode: `_layer_full` over a whole
+sequence (train, eval, the masked forward and, returning each layer's
+decode state, prefill) and `_layer_decode` over one token (`decode_step`
+and both served halves, all through `_decode_layer_range`). zamba2's
+shared block is written once per mode too: `_shared_seq` and
+`_shared_step`, whose cache stack rides in the loop carry. The loops only
+call these; `_final_head` picks the final exit's head for all of them.
+
 Typed layers keep their mixers' weights and decode state once per type:
 ``params["mixers"]`` holds a stacked "mamba" and a stacked "attention"
 tree, and the cache tree a stacked "ssm" and a stacked "attn" entry, each
@@ -278,7 +286,7 @@ def _positions(cfg: ModelConfig, b: int, s: int, offset=0):
     return pos
 
 
-# -------------------------------------------------------------- typed layers
+# ------------------------------------------------------------------ layers
 
 def _attn_kw(cfg: ModelConfig):
     return dict(num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
@@ -290,6 +298,39 @@ def _attn_kw(cfg: ModelConfig):
 def _mamba_kw(cfg: ModelConfig):
     return dict(state_size=cfg.ssm.state_size, expand=cfg.ssm.expand,
                 chunk=cfg.ssm.chunk_size, norm_eps=cfg.norm_eps)
+
+
+def _shared_block(cfg: ModelConfig) -> bool:
+    """zamba2's layout: a Mamba2 backbone with one shared attention block
+    after every ``hybrid_attn_every``-th layer."""
+    return cfg.family == "hybrid" and not cfg.layer_types
+
+
+def _ring_fill(cache, k, v):
+    """``cache`` with the last of a prompt's keys and values ``k``, ``v``
+    (B, S, Hkv, hd) written at their ring slots."""
+    w, s = cache["k"].shape[1], k.shape[1]
+    return attn.fill_cache(cache, k[:, -w:], v[:, -w:], start=max(0, s - w))
+
+
+def _new_cache(cfg: ModelConfig, b: int, w: int):
+    return attn.init_cache(b, w, cfg.num_kv_heads, cfg.resolved_head_dim,
+                           jnp.dtype(cfg.dtype))
+
+
+def _rwkv_layer(cfg: ModelConfig, lp, x, st, **kw):
+    """An RWKV6 layer from its state ``st`` (a zero state where None): time
+    mix, then channel mix, each a residual. Returns (x, new state)."""
+    heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
+    if st is None:
+        st = rk.init_rwkv_state(x.shape[0], cfg.d_model, heads)
+    h, (tm_last, wkv) = rk.time_mix(
+        lp["tm"], _norm(cfg, x, lp["ln1"]), (st["tm_last"], st["wkv"]),
+        num_heads=heads, **kw)
+    x = x + h
+    h, cm_last = rk.channel_mix(lp["cm"], _norm(cfg, x, lp["ln2"]),
+                                st["cm_last"])
+    return x + h, {"tm_last": tm_last, "cm_last": cm_last, "wkv": wkv}
 
 
 def _typed_block(cfg: ModelConfig, lp, x, mixer):
@@ -305,14 +346,14 @@ def _typed_block(cfg: ModelConfig, lp, x, mixer):
     return x + r * h, state
 
 
-def _typed_mixer_seq(cfg: ModelConfig, lp, xn, positions, *, window: int,
-                     backend: str, cache_window: int = 0):
-    """A typed layer's mixer over a whole sequence ``xn`` (B, S, D): Mamba2
-    (``lp["mamba"]``) from a zero state, or attention (``lp["attn"]``).
+def _mixer_seq(cfg: ModelConfig, lp, xn, positions, *, window: int,
+               backend: str, cache_window: int = 0):
+    """A Mamba2 (``lp["mamba"]``) or typed attention (``lp["attn"]``) mixer
+    over a whole sequence ``xn`` (B, S, D), Mamba2 from a zero state.
     Returns (h, state): Mamba2's final SSM and conv state, or, with a
     ``cache_window`` (prefill), the attention cache holding the last keys
     and values at their ring slots (else None)."""
-    b, s, _ = xn.shape
+    b = xn.shape[0]
     if "mamba" in lp:
         with jax.named_scope("splitee.mamba"):
             st = m2.init_mamba2_state(b, cfg.d_model, cfg.ssm.state_size,
@@ -324,18 +365,13 @@ def _typed_mixer_seq(cfg: ModelConfig, lp, xn, positions, *, window: int,
             backend=backend, return_kv=True, **_attn_kw(cfg))
         if not cache_window:
             return h, None
-        w = cache_window
-        c = attn.init_cache(b, w, cfg.num_kv_heads, cfg.resolved_head_dim,
-                            jnp.dtype(cfg.dtype))
-        return h, attn.fill_cache(c, k[:, -w:], v[:, -w:],
-                                  start=max(0, s - w))
+        return h, _ring_fill(_new_cache(cfg, b, cache_window), k, v)
 
 
-def _typed_mixer_step(cfg: ModelConfig, lp, xn, st, cur_index, *,
-                      window: int):
-    """A typed layer's mixer for one token ``xn`` (B, 1, D) from its state
-    ``st``: Mamba2 (``lp["mamba"]``) or attention (``lp["attn"]``).
-    Returns (h, new state)."""
+def _mixer_step(cfg: ModelConfig, lp, xn, st, cur_index, *, window: int):
+    """A Mamba2 (``lp["mamba"]``) or attention (``lp["attn"]``) mixer for
+    one token ``xn`` (B, 1, D) from its state ``st``. Returns (h, new
+    state)."""
     if "mamba" in lp:
         with jax.named_scope("splitee.mamba"):
             return m2.mamba2_forward(lp["mamba"], xn, st, **_mamba_kw(cfg))
@@ -344,60 +380,91 @@ def _typed_mixer_step(cfg: ModelConfig, lp, xn, st, cur_index, *,
                                 window=window, **_attn_kw(cfg))
 
 
-# ------------------------------------------------------------ full-seq layer
+def _shared_seq(cfg: ModelConfig, params, x, positions, i, occ, *,
+                window: int, backend: str):
+    """zamba2's shared attention block over the sequence, run after layer
+    ``i`` where ``(i + 1) % hybrid_attn_every == 0``. With ``occ`` (the
+    block's stacked caches, prefill) its keys and values fill the cache of
+    this occurrence. Returns (x, occ)."""
+    k = cfg.hybrid_attn_every
+    sp = params["shared_attn"]
+
+    def block(args):
+        xx, occ = args
+        oi = (i + 1) // k - 1
+        h, (kk, vv) = attn.attn_prefill(
+            sp["attn"], _norm(cfg, xx, sp["ln1"]), positions,
+            causal=cfg.causal, window=window, backend=backend,
+            return_kv=True, **_attn_kw(cfg))
+        xx = xx + h
+        xx = xx + ff.mlp_forward(sp["mlp"], _norm(cfg, xx, sp["ln2"]),
+                                 cfg.activation)
+        if occ is not None:
+            sl = _ring_fill(jax.tree.map(lambda a: a[oi], occ), kk, vv)
+            occ = jax.tree.map(
+                lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
+                    buf, ns.astype(buf.dtype), oi, 0), occ, sl)
+        return xx, occ
+
+    return jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0), block,
+                        lambda a: a, (x, occ))
+
+
+def _shared_step(cfg: ModelConfig, params, x, occ, i, live, cur_index, *,
+                 window: int):
+    """zamba2's shared attention block for one token, run after layer
+    ``i`` where ``(i + 1) % hybrid_attn_every == 0``; its cache in ``occ``
+    advances for the rows in ``live`` (B,) only. Returns (x, occ)."""
+    k = cfg.hybrid_attn_every
+    sp = params["shared_attn"]
+
+    def block(args):
+        xx, occ = args
+        oi = (i + 1) // k - 1
+        sl = jax.tree.map(lambda a: a[oi], occ)
+        h, new_sl = attn.attn_decode(
+            sp["attn"], _norm(cfg, xx, sp["ln1"]), sl, cur_index,
+            window=window, **_attn_kw(cfg))
+        xx = xx + h
+        xx = xx + ff.mlp_forward(sp["mlp"], _norm(cfg, xx, sp["ln2"]),
+                                 cfg.activation)
+        new_sl = _mask_rows(live, new_sl, sl)
+        occ = jax.tree.map(
+            lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
+                buf, ns, oi, 0), occ, new_sl)
+        return xx, occ
+
+    return jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0), block,
+                        lambda a: a, (x, occ))
+
 
 def _layer_full(cfg: ModelConfig, params, lp, x, positions, i, *,
-                window: int, backend: str):
-    """One layer over the full sequence. Returns (x, aux)."""
+                window: int, backend: str, cache_window: int = 0, occ=None):
+    """One layer over the full sequence. Returns (x, aux).
+
+    With a ``cache_window`` (prefill) it returns (x, aux, state, occ):
+    the layer's decode state (an attention layer's ring cache of that many
+    slots holding the last keys and values, RWKV6's or Mamba2's final
+    state) and ``occ``, zamba2's shared-block caches, filled where the
+    block runs after this layer (None elsewhere)."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.layer_types:
-        x, _ = _typed_block(cfg, lp, x, lambda xn: _typed_mixer_seq(
-            cfg, lp, xn, positions, window=window, backend=backend))
+        x, st = _typed_block(cfg, lp, x, lambda xn: _mixer_seq(
+            cfg, lp, xn, positions, window=window, backend=backend,
+            cache_window=cache_window))
     elif cfg.family == "ssm":
-        b = x.shape[0]
-        heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
-        st = rk.init_rwkv_state(b, cfg.d_model, heads)
-        h, _ = rk.time_mix(lp["tm"], _norm(cfg, x, lp["ln1"]),
-                           (st["tm_last"], st["wkv"]), num_heads=heads,
-                           backend=backend, chunk=cfg.ssm.chunk_size)
-        x = x + h
-        h, _ = rk.channel_mix(lp["cm"], _norm(cfg, x, lp["ln2"]),
-                              st["cm_last"])
-        x = x + h
+        x, st = _rwkv_layer(cfg, lp, x, None, backend=backend,
+                            chunk=cfg.ssm.chunk_size)
     elif cfg.family == "hybrid":
-        b = x.shape[0]
-        st = m2.init_mamba2_state(b, cfg.d_model, cfg.ssm.state_size,
-                                  cfg.ssm.expand)
-        h, _ = m2.mamba2_forward(
-            lp["mamba"], _norm(cfg, x, lp["ln1"]), st,
-            state_size=cfg.ssm.state_size, expand=cfg.ssm.expand,
-            chunk=cfg.ssm.chunk_size)
-        x = x + h
-
-        def shared_block(xx):
-            sp = params["shared_attn"]
-            h2 = attn.attn_prefill(
-                sp["attn"], _norm(cfg, xx, sp["ln1"]), positions,
-                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-                window=window, rope_theta=cfg.attn_rope_theta,
-                qk_norm=cfg.qk_norm, backend=backend)
-            xx = xx + h2
-            h2 = ff.mlp_forward(sp["mlp"],
-                                _norm(cfg, xx, sp["ln2"]),
-                                cfg.activation)
-            return xx + h2
-
-        k = cfg.hybrid_attn_every
-        x = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
-                         shared_block, lambda xx: xx, x)
+        h, st = _mixer_seq(cfg, lp, _norm(cfg, x, lp["ln1"]), positions,
+                           window=window, backend=backend)
+        x, occ = _shared_seq(cfg, params, x + h, positions, i, occ,
+                             window=window, backend=backend)
     else:
-        h = attn.attn_prefill(
+        h, (k, v) = attn.attn_prefill(
             lp["attn"], _norm(cfg, x, lp["ln1"]), positions,
-            num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-            window=window, rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm,
-            mrope=cfg.mrope, backend=backend)
+            causal=cfg.causal, window=window, backend=backend,
+            return_kv=True, **_attn_kw(cfg))
         x = x + h
         x2 = _norm(cfg, x, lp["ln2"])
         if cfg.family == "moe":
@@ -408,7 +475,12 @@ def _layer_full(cfg: ModelConfig, params, lp, x, positions, i, *,
         else:
             h = ff.mlp_forward(lp["mlp"], x2, cfg.activation)
         x = x + h
-    return constrain(x, "batch", None, None), aux
+        st = (_ring_fill(_new_cache(cfg, x.shape[0], cache_window), k, v)
+              if cache_window else None)
+    x = constrain(x, "batch", None, None)
+    if not cache_window:
+        return x, aux
+    return x, aux, st, occ
 
 
 # -------------------------------------------------------------- train / eval
@@ -462,9 +534,7 @@ def train_loss(params, cfg: ModelConfig, batch: Dict[str, Any], *,
         cfg, params, body_fn, (x, jnp.zeros((), jnp.float32)))
 
     xf = _head_norm(cfg, x, params["final_norm"])
-    w = params.get("exit_w")
-    if w is None:  # per-exit heads: final exit = last layer's head
-        w = jax.tree.map(lambda l: l[-1], params["layers"])["exit_w"]
+    w = _final_head(params, cfg)
     if cfg.num_classes:
         final_logits = pool_hidden(cfg, xf) @ w
         final_loss = cross_entropy(final_logits, labels)
@@ -509,12 +579,37 @@ def forward_exits(params, cfg: ModelConfig, batch: Dict[str, Any], *,
     return {"conf": conf, "pred": pred, "hidden": x}
 
 
+def _shared_head(cfg: ModelConfig) -> bool:
+    return cfg.exits.share_head or not cfg.exits.enabled
+
+
 def _exit_heads(params, cfg: ModelConfig):
     """Every exit's head: the shared (D, V) matrix, or the per-layer
     (L, D, V) stack."""
-    if cfg.exits.share_head or not cfg.exits.enabled:
+    if _shared_head(cfg):
         return params["exit_w"]
     return params["layers"]["exit_w"]
+
+
+def _final_head(params, cfg: ModelConfig):
+    """The final exit's head: the shared one, or the last layer's."""
+    if _shared_head(cfg):
+        return params["exit_w"]
+    return params["layers"]["exit_w"][-1]
+
+
+def _last_logits(params, cfg: ModelConfig, x):
+    """The final head on the last position of ``x`` (B, S, D): (B, V)."""
+    ew = _final_head(params, cfg)
+    xf = _head_norm(cfg, x, params["final_norm"])
+    return constrain(xf[:, -1, :] @ ew, "batch", "model")
+
+
+def _exit_pools(params, cfg: ModelConfig, hs):
+    """Every exit's pooled, normed hidden (L, B, D) from the carries ``hs``
+    (L, B, 1, D) its layers read."""
+    return jax.vmap(lambda h, n: pool_hidden(cfg, _head_norm(cfg, h, n)))(
+        hs, params["layers"]["exit_norm"])
 
 
 def stacked_exit_confidence(params, cfg: ModelConfig, pooled, *,
@@ -632,34 +727,18 @@ def init_caches(cfg: ModelConfig, batch: int, seq_len: int):
 
 def _layer_decode(cfg: ModelConfig, params, lp, x, cache_slice, cur_index, *,
                   window: int):
-    """One-token decode through one layer. Returns (x, new_cache_slice)."""
+    """One-token decode through one layer. Returns (x, new_cache_slice).
+    zamba2's shared block is not part of it (`_shared_step`)."""
     if cfg.layer_types:
-        return _typed_block(cfg, lp, x, lambda xn: _typed_mixer_step(
+        return _typed_block(cfg, lp, x, lambda xn: _mixer_step(
             cfg, lp, xn, cache_slice, cur_index, window=window))
     if cfg.family == "ssm":
-        st = cache_slice
-        heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
-        h, (tm_last, wkv) = rk.time_mix(
-            lp["tm"], _norm(cfg, x, lp["ln1"]),
-            (st["tm_last"], st["wkv"]), num_heads=heads)
-        x = x + h
-        h, cm_last = rk.channel_mix(
-            lp["cm"], _norm(cfg, x, lp["ln2"]), st["cm_last"])
-        x = x + h
-        return x, {"tm_last": tm_last, "cm_last": cm_last, "wkv": wkv}
-    if cfg.family == "hybrid":
-        st = cache_slice
-        h, new_st = m2.mamba2_forward(
-            lp["mamba"], _norm(cfg, x, lp["ln1"]), st,
-            state_size=cfg.ssm.state_size, expand=cfg.ssm.expand)
-        x = x + h
-        return x, new_st
-    h, new_cache = attn.attn_decode(
-        lp["attn"], _norm(cfg, x, lp["ln1"]), cache_slice,
-        cur_index, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        head_dim=cfg.resolved_head_dim, window=window,
-        rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm, mrope=cfg.mrope)
+        return _rwkv_layer(cfg, lp, x, cache_slice)
+    h, new_cache = _mixer_step(cfg, lp, _norm(cfg, x, lp["ln1"]),
+                               cache_slice, cur_index, window=window)
     x = x + h
+    if cfg.family == "hybrid":
+        return x, new_cache
     x2 = _norm(cfg, x, lp["ln2"])
     if cfg.family == "moe":
         # decode is drop-free: capacity covers the all-tokens-to-one-expert
@@ -687,85 +766,34 @@ def _embed_step(params, cfg: ModelConfig, token_or_embed):
 def decode_step(params, cfg: ModelConfig, caches, token_or_embed,
                 cur_index, *, split_layer=None, all_exits: bool = False,
                 window_seq_len: int = 0, conf_backend: str = "ref"):
-    """SplitEE serve step: decode ONE token with per-layer pooled hiddens
-    collected; exit confidence evaluated at ``split_layer`` (SplitEE) or at
-    every exit (``all_exits`` — SplitEE-S). Returns (logits, conf, pred,
+    """SplitEE serve step: decode ONE token through every layer, every row
+    live (the layer loop of the served halves, `_decode_layer_range`), with
+    exit confidence evaluated at ``split_layer`` (SplitEE) or at every exit
+    (``all_exits`` — SplitEE-S). Returns (logits, conf, pred,
     new_caches).
     """
     x = _embed_step(params, cfg, token_or_embed)
-    b = x.shape[0]
-    window = cfg.effective_window(window_seq_len)
+    every = jnp.ones(x.shape[:1], bool)
+    x, new_caches, buf = _decode_layer_range(
+        params, cfg, caches, x, cur_index, 0, cfg.num_layers,
+        lambda i: every, window=cfg.effective_window(window_seq_len),
+        record=True)
 
-    if cfg.family == "hybrid" and not cfg.layer_types:
-        k = cfg.hybrid_attn_every
-        sp = params["shared_attn"]
-
-        def body(carry, inp):
-            xx, occ = carry
-            lp, st, i = inp
-            xx, new_st = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                       window=window)
-
-            def with_attn(args):
-                xx, occ = args
-                oi = (i + 1) // k - 1
-                sl = jax.tree.map(lambda a: a[oi], occ)
-                h, new_sl = attn.attn_decode(
-                    sp["attn"], _norm(cfg, xx, sp["ln1"]), sl,
-                    cur_index, num_heads=cfg.num_heads,
-                    num_kv_heads=cfg.num_kv_heads,
-                    head_dim=cfg.resolved_head_dim, window=window,
-                    rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm)
-                xx = xx + h
-                xx = xx + ff.mlp_forward(
-                    sp["mlp"], _norm(cfg, xx, sp["ln2"]),
-                    cfg.activation)
-                occ = jax.tree.map(
-                    lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
-                        buf, ns, oi, 0), occ, new_sl)
-                return xx, occ
-
-            xx, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
-                                   with_attn, lambda a: a, (xx, occ))
-            pooled = pool_hidden(cfg, _head_norm(cfg, xx, lp["exit_norm"]))
-            return (xx, occ), (new_st, pooled)
-
-        idx = jnp.arange(cfg.num_layers)
-        (x, occ), (new_ssm, pooled) = jax.lax.scan(
-            body, (x, caches["attn"]), (params["layers"], caches["ssm"], idx),
-            unroll=_unroll())
-        new_caches = {"ssm": new_ssm, "attn": occ}
-    else:
-        def body(xx, inp):
-            lp, st, i = inp
-            xx, new_st = _layer_decode(cfg, params, lp, xx, st, cur_index,
-                                       window=window)
-            pooled = pool_hidden(cfg, _head_norm(cfg, xx, lp["exit_norm"]))
-            return xx, (new_st, pooled)
-
-        x, new_caches, pooled = _scan_layers(cfg, params, body, x, caches)
-
-    # exit observables (post-scan: one gather + one fused confidence call)
-    shared = cfg.exits.share_head or not cfg.exits.enabled
-    if shared:
-        ew = params["exit_w"]
-    else:
-        ew = params["layers"]["exit_w"][-1]              # final exit's head
+    # exit observables (post-loop: one gather + one fused confidence call)
+    pooled = _exit_pools(params, cfg, buf)
     if all_exits:
         conf, pred = stacked_exit_confidence(params, cfg, pooled,
                                              conf_backend=conf_backend)
     elif split_layer is not None:
         h_split = jax.lax.dynamic_index_in_dim(pooled, split_layer, 0,
                                                keepdims=False)
-        w_split = ew if shared else jax.lax.dynamic_index_in_dim(
-            params["layers"]["exit_w"], split_layer, 0, keepdims=False)
+        ews = _exit_heads(params, cfg)
+        w_split = ews if _shared_head(cfg) else jax.lax.dynamic_index_in_dim(
+            ews, split_layer, 0, keepdims=False)
         conf, pred = exit_confidence(h_split, w_split, backend=conf_backend)
     else:
         conf = pred = None
-
-    xf = _head_norm(cfg, x, params["final_norm"])
-    logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
-    return logits, conf, pred, new_caches
+    return _last_logits(params, cfg, x), conf, pred, new_caches
 
 
 def _mask_rows(mask, new, old):
@@ -780,25 +808,23 @@ def _mask_rows(mask, new, old):
 
 def _decode_layer_range(params, cfg: ModelConfig, caches, x, cur_index,
                         lo, hi, live, *, window, record: bool = False):
-    """Run decode layers ``lo .. hi-1`` of one step (bounds traced, so the
-    loop's trip count is dynamic) over the stacked cache tree. ``live(i)``
-    gives the (B,) rows layer ``i`` advances; the others keep their carry
-    and cache slices. Each layer's parameters and cache slice are read with
-    a dynamic index and the slice is written back in place into the carried
-    tree, so the loop neither computes nor touches the layers outside the
-    range (the caches are not donated, so XLA copies the input tree into
-    the carry once). Typed layers run one loop per run of one type
-    (`_runs`), each over its part of the range.
+    """Run decode layers ``lo .. hi-1`` of one step over the stacked cache
+    tree. ``live(i)`` gives the (B,) rows layer ``i`` advances; the others
+    keep their carry and cache slices. Each layer's parameters and cache
+    slice are read with a dynamic index and the slice is written back in
+    place into the carried tree, so the loop neither computes nor touches
+    the layers outside the range (the caches are not donated, so XLA
+    copies the input tree into the carry once). Typed layers run one loop
+    per run of one type (`_runs`), each over its part of the range.
+    Traced bounds make the trip count dynamic; where both are Python ints
+    the loops are scans unrolled by ``LAYER_SCAN_UNROLL``.
 
     Returns (x, new_caches, buf): with ``record``, ``buf`` (L, B, 1, D)
     holds the carry after each layer that ran (zeros from ``hi`` up).
     """
-    hybrid = cfg.family == "hybrid" and not cfg.layer_types
     take = functools.partial(jax.lax.dynamic_index_in_dim, keepdims=False)
     put = jax.lax.dynamic_update_index_in_dim
-    if hybrid:
-        k = cfg.hybrid_attn_every
-        sp = params["shared_attn"]
+    static = isinstance(lo, int) and isinstance(hi, int)
 
     def run_body(kind, off):
         # layer i's state is entry i + off of its stack
@@ -810,29 +836,9 @@ def _decode_layer_range(params, cfg: ModelConfig, caches, x, cur_index,
             m = live(i)
             xx2, new_st = _layer_decode(cfg, params, lp, xx, st, cur_index,
                                         window=window)
-            if hybrid:
-                def with_attn(args):
-                    xx2, occ = args
-                    oi = (i + 1) // k - 1
-                    sl = jax.tree.map(lambda a: a[oi], occ)
-                    h, new_sl = attn.attn_decode(
-                        sp["attn"], _norm(cfg, xx2, sp["ln1"]), sl,
-                        cur_index, num_heads=cfg.num_heads,
-                        num_kv_heads=cfg.num_kv_heads,
-                        head_dim=cfg.resolved_head_dim, window=window,
-                        rope_theta=cfg.attn_rope_theta, qk_norm=cfg.qk_norm)
-                    xx2 = xx2 + h
-                    xx2 = xx2 + ff.mlp_forward(
-                        sp["mlp"], _norm(cfg, xx2, sp["ln2"]),
-                        cfg.activation)
-                    # shared cache: advance only the rows this layer advances
-                    new_sl = _mask_rows(m, new_sl, sl)
-                    occ = jax.tree.map(lambda buf, ns: put(buf, ns, oi, 0),
-                                       occ, new_sl)
-                    return xx2, occ
-
-                xx2, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
-                                        with_attn, lambda a: a, (xx2, occ))
+            if occ is not None:
+                xx2, occ = _shared_step(cfg, params, xx2, occ, i, m,
+                                        cur_index, window=window)
             xx = jnp.where(m[:, None, None], xx2, xx)
             new_st = _mask_rows(m, new_st, st)
             stack = jax.tree.map(lambda a, s: put(a, s, j, 0), stack, new_st)
@@ -843,19 +849,24 @@ def _decode_layer_range(params, cfg: ModelConfig, caches, x, cur_index,
 
     buf = (jnp.zeros((cfg.num_layers,) + x.shape, x.dtype) if record
            else None)
-    occ = caches["attn"] if hybrid else None
+    occ = caches["attn"] if _shared_block(cfg) else None
     new_caches = dict(caches)
     with jax.named_scope("splitee.layers"):
         for kind, a, b, s in _runs(cfg):
             key = _state_key(cfg, kind)
             # a run of typed layers loops over its part of lo .. hi-1 (none
             # where they do not meet)
-            bounds = (lo, hi) if kind is None else (jnp.clip(lo, a, b),
-                                                    jnp.clip(hi, a, b))
+            if kind is None:
+                bounds = (lo, hi)
+            elif static:
+                bounds = (min(max(lo, a), b), min(max(hi, a), b))
+            else:
+                bounds = (jnp.clip(lo, a, b), jnp.clip(hi, a, b))
             x, new_caches[key], occ, buf = jax.lax.fori_loop(
                 *bounds, run_body(kind, s - a),
-                (x, new_caches[key], occ, buf))
-    if hybrid:
+                (x, new_caches[key], occ, buf),
+                unroll=_unroll() if static else None)
+    if occ is not None:
         new_caches["attn"] = occ
     return x, new_caches, buf
 
@@ -889,17 +900,13 @@ def decode_step_masked(params, cfg: ModelConfig, caches, token_or_embed,
     # every exit's hidden: the carry after layer i up to a row's depth,
     # its final carry above it (where the masked rows stood still)
     ran = jnp.arange(L)[:, None] <= depths[None, :]
-    hs = jnp.where(ran[:, :, None, None], buf, x[None])
-    pooled = jax.vmap(lambda h, n: pool_hidden(cfg, _head_norm(
-        cfg, h, n)))(hs, params["layers"]["exit_norm"])
+    pooled = _exit_pools(params, cfg,
+                         jnp.where(ran[:, :, None, None], buf, x[None]))
     with jax.named_scope("splitee.exit_heads"):
         conf, pred = stacked_exit_confidence(params, cfg, pooled,
                                              conf_backend=conf_backend)
     with jax.named_scope("splitee.final_head"):
-        ews = _exit_heads(params, cfg)
-        ew = ews if ews.ndim == 2 else ews[-1]       # final exit's head
-        xf = _head_norm(cfg, x, params["final_norm"])
-        logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
+        logits = _last_logits(params, cfg, x)
     return logits, conf, pred, x, new_caches
 
 
@@ -926,20 +933,15 @@ def decode_step_resume(params, cfg: ModelConfig, caches, hidden,
         window=cfg.effective_window(window_seq_len))
 
     with jax.named_scope("splitee.final_head"):
-        ew = params["exit_w"] if "exit_w" in params \
-            else params["layers"]["exit_w"][-1]
-        xf = _head_norm(cfg, x, params["final_norm"])
-        logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
+        logits = _last_logits(params, cfg, x)
     return logits, new_caches
 
 
 def prefill(params, cfg: ModelConfig, batch: Dict[str, Any], *,
             backend: str = "ref", cache_seq_len: int = 0):
-    """Process the prompt, build decode caches, return final logits.
-
-    For attention archs the prefill recomputes K/V into the cache via a
-    scan that mirrors the train-mode layer but returns (k, v) as ys.
-    """
+    """Process the prompt, build decode caches, return final logits: the
+    sequence layer (`_layer_full`) over every layer, each returning its
+    decode state."""
     x = embed_inputs(params, cfg, batch)
     b, s, _ = x.shape
     positions = _positions(cfg, b, s)
@@ -947,121 +949,17 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, Any], *,
     window = cfg.effective_window(seq_total)
     cache_window = window or seq_total
 
-    if cfg.layer_types:
-        def body(xx, inp):
-            lp, _, _ = inp
-            xx, st = _typed_block(cfg, lp, xx, lambda xn: _typed_mixer_seq(
-                cfg, lp, xn, positions, window=window, backend=backend,
-                cache_window=cache_window))
-            return constrain(xx, "batch", None, None), (st, None)
+    def body(carry, inp):
+        xx, occ = carry
+        lp, _, i = inp
+        xx, _, st, occ = _layer_full(
+            cfg, params, lp, xx, positions, i, window=window,
+            backend=backend, cache_window=cache_window, occ=occ)
+        return (xx, occ), (st, None)
 
-        x, caches, _ = _scan_layers(cfg, params, body, x)
-    elif cfg.family == "ssm":
-        def body(carry, inp):
-            xx = carry
-            lp, i = inp
-            heads = cfg.ssm.num_heads or cfg.d_model // cfg.ssm.state_size
-            st = rk.init_rwkv_state(b, cfg.d_model, heads)
-            h, (tm_last, wkv) = rk.time_mix(
-                lp["tm"], _norm(cfg, xx, lp["ln1"]),
-                (st["tm_last"], st["wkv"]), num_heads=heads, backend=backend,
-                chunk=cfg.ssm.chunk_size)
-            xx = xx + h
-            h, cm_last = rk.channel_mix(
-                lp["cm"], _norm(cfg, xx, lp["ln2"]), st["cm_last"])
-            xx = xx + h
-            return xx, {"tm_last": tm_last, "cm_last": cm_last, "wkv": wkv}
-
-        idx = jnp.arange(cfg.num_layers)
-        x, states = jax.lax.scan(body, x, (params["layers"], idx),
-                                 unroll=_unroll())
-        caches = {"ssm": states}
-    elif cfg.family == "hybrid":
-        k = cfg.hybrid_attn_every
-        sp = params["shared_attn"]
-        n_attn = cfg.num_layers // k
-        occ0 = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (n_attn,) + a.shape),
-            attn.init_cache(b, cache_window, cfg.num_kv_heads,
-                            cfg.resolved_head_dim, jnp.dtype(cfg.dtype)))
-
-        def body(carry, inp):
-            xx, occ = carry
-            lp, i = inp
-            st = m2.init_mamba2_state(b, cfg.d_model, cfg.ssm.state_size,
-                                      cfg.ssm.expand)
-            h, new_st = m2.mamba2_forward(
-                lp["mamba"], _norm(cfg, xx, lp["ln1"]), st,
-                state_size=cfg.ssm.state_size, expand=cfg.ssm.expand,
-                chunk=cfg.ssm.chunk_size)
-            xx = xx + h
-
-            def with_attn(args):
-                xx, occ = args
-                oi = (i + 1) // k - 1
-                h2, (kk, vv) = attn.attn_prefill(
-                    sp["attn"], _norm(cfg, xx, sp["ln1"]),
-                    positions, num_heads=cfg.num_heads,
-                    num_kv_heads=cfg.num_kv_heads,
-                    head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-                    window=window, rope_theta=cfg.attn_rope_theta,
-                    qk_norm=cfg.qk_norm, backend=backend, return_kv=True)
-                xx = xx + h2
-                xx = xx + ff.mlp_forward(
-                    sp["mlp"], _norm(cfg, xx, sp["ln2"]),
-                    cfg.activation)
-                sl = jax.tree.map(lambda a: a[oi], occ)
-                sl = attn.fill_cache(sl, kk[:, -cache_window:],
-                                     vv[:, -cache_window:],
-                                     start=max(0, s - cache_window))
-                occ = jax.tree.map(
-                    lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
-                        buf, ns.astype(buf.dtype), oi, 0), occ, sl)
-                return xx, occ
-
-            xx, occ = jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0),
-                                   with_attn, lambda a: a, (xx, occ))
-            return (xx, occ), new_st
-
-        idx = jnp.arange(cfg.num_layers)
-        (x, occ), states = jax.lax.scan(body, (x, occ0),
-                                        (params["layers"], idx),
-                                        unroll=_unroll())
-        caches = {"ssm": states, "attn": occ}
-    else:
-        def body(xx, inp):
-            lp, i = inp
-            h, (kk, vv) = attn.attn_prefill(
-                lp["attn"], _norm(cfg, xx, lp["ln1"]), positions,
-                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                head_dim=cfg.resolved_head_dim, causal=cfg.causal,
-                window=window, rope_theta=cfg.attn_rope_theta,
-                qk_norm=cfg.qk_norm, mrope=cfg.mrope, backend=backend,
-                return_kv=True)
-            xx = xx + h
-            x2 = _norm(cfg, xx, lp["ln2"])
-            if cfg.family == "moe":
-                h, _ = ff.moe_forward(
-                    lp["moe"], x2, num_experts=cfg.moe.num_experts,
-                    top_k=cfg.moe.top_k,
-                    capacity_factor=cfg.moe.capacity_factor)
-            else:
-                h = ff.mlp_forward(lp["mlp"], x2, cfg.activation)
-            xx = constrain(xx + h, "batch", None, None)
-            c = attn.init_cache(b, cache_window, cfg.num_kv_heads,
-                                cfg.resolved_head_dim, jnp.dtype(cfg.dtype))
-            c = attn.fill_cache(c, kk[:, -cache_window:],
-                                vv[:, -cache_window:],
-                                start=max(0, s - cache_window))
-            return xx, c
-
-        idx = jnp.arange(cfg.num_layers)
-        x, caches_stacked = jax.lax.scan(body, x, (params["layers"], idx),
-                                         unroll=_unroll())
-        caches = {"attn": caches_stacked}
-
-    ew = params["exit_w"] if "exit_w" in params \
-        else params["layers"]["exit_w"][-1]
-    xf = _head_norm(cfg, x, params["final_norm"])
-    logits = constrain(xf[:, -1, :] @ ew, "batch", "model")
-    return logits, caches
+    occ = init_caches(cfg, b, seq_total)["attn"] if _shared_block(cfg) \
+        else None
+    (x, occ), caches, _ = _scan_layers(cfg, params, body, (x, occ))
+    if occ is not None:
+        caches["attn"] = occ
+    return _last_logits(params, cfg, x), caches

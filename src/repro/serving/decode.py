@@ -37,10 +37,12 @@ One bandit round per decode step; the communication term is per-arm (an
 (L,) ``offload_scale`` — deeper splits ship strictly more cache slice).
 
 ``split_policy="final"`` forces arm L-1 every step, which makes the whole
-pipeline collapse to plain full-depth ``decode_step`` generation —
-bit-identically (logits, tokens, and final cache state), the differential
-pin in tests/test_decode_serving.py and the baseline every decode
-benchmark compares against.
+pipeline collapse to plain full-depth generation — bit-identically
+(logits, tokens, and final cache state) to the test oracle's full
+``lax.scan`` sweep with every row live (the differential pin in
+tests/test_decode_serving.py), and the baseline every decode benchmark
+compares against. ``transformer.decode_step`` runs the same layer loop as
+the two halves, over every layer with every row live.
 
 Driven by `serving.api`: ``ServingConfig(workload="decode", ...)`` routes
 `serve()`/`Engine` here; `_DecodeSession` mirrors `_BatchedSession`'s

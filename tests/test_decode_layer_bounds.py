@@ -3,7 +3,9 @@
 (``decode_step_resume``) starts above the shallowest active split. Both
 must return bitwise what a full sweep over every layer with per-row masks
 returns: logits, every exit's confidence and prediction, the shipped
-hidden and every cache leaf. The full sweep is kept here as the oracle.
+hidden and every cache leaf. The full sweep is the oracle in
+``_decode_oracle``; ``decode_step`` runs the same bounded loop over every
+layer with every row live, and is held to it too.
 """
 import dataclasses
 
@@ -12,13 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _decode_oracle import full_sweep, head, plain_step
 from repro.configs import get_smoke_config
-from repro.models import attention as attn
-from repro.models import mlp as ff
 from repro.models import transformer as tf
 from repro.models.api import build_model
-from repro.models.common import apply_norm
-from repro.sharding import constrain
 
 # dense, ssm, hybrid with a shared block, hybrid with typed layers
 ARCHS = ["qwen3-1.7b", "rwkv6-3b", "zamba2-1.2b", "granite-4.0-h-micro"]
@@ -26,85 +25,24 @@ L, B, S, TOTAL = 4, 4, 3, 6
 # the typed hybrid's pattern at depth L: the mixed depths below exit at
 # Mamba layers 0 and 1 and skip the attention layer 2 in some rows
 TYPES = ("mamba", "mamba", "attention", "mamba")
-
-
-def _shared_attn(cfg, params, xx2, occ, i, m, cur_index, window):
-    """The hybrid family's shared attention block after every k-th layer,
-    its cache advanced for the rows in ``m`` only."""
-    k = cfg.hybrid_attn_every
-    sp = params["shared_attn"]
-
-    def with_attn(args):
-        xx2, occ = args
-        oi = (i + 1) // k - 1
-        sl = jax.tree.map(lambda a: a[oi], occ)
-        h, new_sl = attn.attn_decode(
-            sp["attn"], apply_norm(xx2, sp["ln1"], cfg.norm), sl,
-            cur_index, num_heads=cfg.num_heads,
-            num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.resolved_head_dim, window=window,
-            rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
-        xx2 = xx2 + h
-        xx2 = xx2 + ff.mlp_forward(
-            sp["mlp"], apply_norm(xx2, sp["ln2"], cfg.norm), cfg.activation)
-        new_sl = tf._mask_rows(m, new_sl, sl)
-        occ = jax.tree.map(
-            lambda buf, ns: jax.lax.dynamic_update_index_in_dim(
-                buf, ns, oi, 0), occ, new_sl)
-        return xx2, occ
-
-    return jax.lax.cond(jnp.equal(jnp.mod(i + 1, k), 0), with_attn,
-                        lambda a: a, (xx2, occ))
-
-
-def _full_sweep(params, cfg, caches, x, cur_index, live, window):
-    """Every layer, rows outside ``live(i)`` masked: (x, caches, pooled)."""
-    hybrid = cfg.family == "hybrid" and not cfg.layer_types
-
-    def body(carry, inp):
-        xx, occ = carry
-        lp, st, i = inp
-        m = live(i)
-        xx2, new_st = tf._layer_decode(cfg, params, lp, xx, st, cur_index,
-                                       window=window)
-        if hybrid:
-            xx2, occ = _shared_attn(cfg, params, xx2, occ, i, m, cur_index,
-                                    window)
-        xx = jnp.where(m[:, None, None], xx2, xx)
-        new_st = tf._mask_rows(m, new_st, st)
-        pooled = tf.pool_hidden(cfg, tf._head_norm(cfg, xx,
-                                                   lp["exit_norm"]))
-        return (xx, occ), (new_st, pooled)
-
-    occ = caches["attn"] if hybrid else None
-    (x, occ), new_caches, pooled = tf._scan_layers(cfg, params, body,
-                                                   (x, occ), caches)
-    if hybrid:
-        new_caches["attn"] = occ
-    return x, new_caches, pooled
-
-
-def _head(params, cfg, x):
-    ews = tf._exit_heads(params, cfg)
-    ew = ews if ews.ndim == 2 else ews[-1]
-    xf = tf._head_norm(cfg, x, params["final_norm"])
-    return constrain(xf[:, -1, :] @ ew, "batch", "model")
+# one case per family: dense, MoE, then the rest of ARCHS
+FAMILIES = ["qwen3-1.7b", "mixtral-8x22b"] + ARCHS[1:]
 
 
 def oracle_masked(params, cfg, caches, token, cur_index, depths):
     x = tf._embed_step(params, cfg, token)
-    x, new_caches, pooled = _full_sweep(
+    x, new_caches, pooled = full_sweep(
         params, cfg, caches, x, cur_index, lambda i: i <= depths,
         cfg.effective_window(TOTAL))
     conf, pred = tf.stacked_exit_confidence(params, cfg, pooled)
-    return _head(params, cfg, x), conf, pred, x, new_caches
+    return head(params, cfg, x), conf, pred, x, new_caches
 
 
 def oracle_resume(params, cfg, caches, hidden, cur_index, depths, active):
-    x, new_caches, _ = _full_sweep(
+    x, new_caches, _ = full_sweep(
         params, cfg, caches, hidden, cur_index,
         lambda i: active & (i > depths), cfg.effective_window(TOTAL))
-    return _head(params, cfg, x), new_caches
+    return head(params, cfg, x), new_caches
 
 
 _BEDS = {}
@@ -133,6 +71,10 @@ def _bed(arch):
                 lambda *a: oracle_masked(a[0], cfg, *a[1:])),
             "oracle_resume": jax.jit(
                 lambda *a: oracle_resume(a[0], cfg, *a[1:])),
+            "decode_step": jax.jit(lambda *a: build_model(cfg).decode_step(
+                *a, all_exits=True, window_seq_len=TOTAL)),
+            "oracle_step": jax.jit(lambda *a: plain_step(
+                a[0], cfg, *a[1:], cfg.effective_window(TOTAL))),
         }
         _BEDS[arch] = (cfg, params, caches, tok, fns)
     return _BEDS[arch]
@@ -170,3 +112,15 @@ def test_layer_range_matches_full_masked_sweep(arch, depths, active):
                                              d, act))
     if active == "none":
         _assert_same(cloud[1], edge_caches)
+
+
+@pytest.mark.parametrize("arch", FAMILIES)
+def test_decode_step_matches_full_sweep(arch):
+    """``Model.decode_step(all_exits=True)`` — the bounded loop over every
+    layer, every row live — gives the oracle's full sweep bitwise: logits,
+    every exit's confidence and prediction, and every cache leaf, in each
+    family (dense, MoE, RWKV6, zamba2's shared block, typed layers)."""
+    cfg, params, caches, tok, fns = _bed(arch)
+    got = fns["decode_step"](params, caches, tok, S)
+    _assert_same(got, fns["oracle_step"](params, caches, tok, S))
+    assert got[1].shape == got[2].shape == (L, B)

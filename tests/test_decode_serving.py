@@ -5,10 +5,12 @@ The suite is the subsystem's bit-identity ladder:
 
 * **Forced-final differential pin** — ``split_policy="final"`` through the
   full `serve()` facade generates bit-identically to a plain full-depth
-  `decode_step` loop (tokens AND per-step logits AND the final cache
-  tree), on a transformer and a recurrent arch at B in {1, 8}. The whole
-  masked-serving machinery must collapse to vanilla decode when no split
-  happens.
+  decode loop (tokens AND per-step logits AND the final cache tree), on a
+  transformer, a recurrent arch and the shared-block hybrid at B in
+  {1, 8}. The plain loop is the test oracle's full ``lax.scan`` sweep
+  with every row live (``_decode_oracle``), not the model's own layer
+  loop. The whole masked-serving machinery must collapse to vanilla
+  decode when no split happens.
 * **Ledger replay property** (vendored hypothesis) — a bandit run's
   recorded per-step realized depths + offload decisions, replayed from a
   FRESH prefill cache through the same edge/cloud programs, regenerate the
@@ -41,9 +43,9 @@ try:
 except ImportError:                                    # vendored fallback
     from _hypothesis_fallback import given, settings, strategies as st
 
+from _decode_oracle import plain_step
 from repro.configs import get_smoke_config
 from repro.core import CostModel
-from repro.models import transformer as tf
 from repro.models.api import build_model
 from repro.serving import (DecodeRuntime, EdgeCloudRuntime, Engine,
                            MultiTenantEngine, ServingConfig, TenantSpec,
@@ -84,12 +86,13 @@ def _trees_equal(a, b):
 
 # ------------------------------------------- forced-final differential pin
 
-@pytest.mark.parametrize("arch", ARCHS)
+@pytest.mark.parametrize("arch", ARCHS + ["zamba2-1.2b"])
 @pytest.mark.parametrize("B", [1, 8])
 def test_forced_final_matches_plain_decode(arch, B):
     """serve(workload='decode', split_policy='final') == a hand-rolled
-    full-depth `decode_step` loop: tokens, per-step logits, and the final
-    cache tree, all bitwise."""
+    full-depth loop of the oracle's full sweep, every row live at every
+    layer under the session's own masks (depth L-1 for every row):
+    tokens, per-step logits, and the final cache tree, all bitwise."""
     cfg, params, rt, cost = _bed(arch)
     L = cfg.num_layers
     total = S + T
@@ -103,16 +106,17 @@ def test_forced_final_matches_plain_decode(arch, B):
 
     # plain full-depth reference, jitted like the serving runtime
     plain = jax.jit(
-        lambda p, c, t, i: tf.decode_step(p, cfg, c, t, i, all_exits=True,
-                                          window_seq_len=total),
+        lambda p, c, t, i, d: plain_step(p, cfg, c, t, i,
+                                         cfg.effective_window(total), d),
         static_argnums=(3,))
+    depths = jnp.full((B,), L - 1, jnp.int32)
     prompts = np.stack([np.asarray(s["tokens"], np.int32) for s in samples])
     logits0, caches = rt.prefill_fn(params, jnp.asarray(prompts), total)
     tok = jnp.argmax(logits0, -1).astype(jnp.int32)
     ref_tokens = np.zeros((B, T), np.int32)
     ref_logits = []
     for t in range(T):
-        lg, _, _, caches = plain(params, caches, tok, S + t)
+        lg, _, _, caches = plain(params, caches, tok, S + t, depths)
         tok = jnp.argmax(lg, -1).astype(jnp.int32)
         ref_tokens[:, t] = np.asarray(tok)
         ref_logits.append(np.asarray(lg))
@@ -123,7 +127,6 @@ def test_forced_final_matches_plain_decode(arch, B):
     # plain loop's tree
     logits0, m_caches = rt.prefill_fn(params, jnp.asarray(prompts), total)
     tok = jnp.argmax(logits0, -1).astype(jnp.int32)
-    depths = jnp.full((B,), L - 1, jnp.int32)
     for t in range(T):
         lg, _, _, _, pred_fin, _, m_caches = rt.edge_fn(
             params, m_caches, tok, S + t, depths, total)
